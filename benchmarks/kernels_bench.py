@@ -22,6 +22,7 @@ from benchmarks import perf_baseline as pb  # noqa: E402
 from benchmarks.common import fmt_row, timed  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
 from repro.kernels import multi_lora as ml  # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def run(report):
@@ -171,6 +172,7 @@ def telemetry_run(out_dir, report=print):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if "--telemetry-out" in argv:
         i = argv.index("--telemetry-out")

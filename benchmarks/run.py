@@ -8,6 +8,8 @@ import sys
 import time
 import traceback
 
+from repro.utils import enable_compile_cache
+
 SUITES = [
     ("table1_memory", "paper Table 1: computation-space complexity"),
     ("equivalence", "paper Tables 2/3/6: method equivalence"),
@@ -22,6 +24,7 @@ SUITES = [
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--only", default=None, help="comma-separated suite names")
     args = p.parse_args(argv)

@@ -35,6 +35,7 @@ from repro.core import gl  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.runtime.serve_loop import Request, ServeEngine  # noqa: E402
 from repro.telemetry.metrics import percentiles  # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def _reset(eng, cfg, slots, max_len):
@@ -456,6 +457,7 @@ def collect() -> list[dict]:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     from benchmarks import perf_baseline as pb
     import jax as _jax
     argv = list(sys.argv[1:] if argv is None else argv)

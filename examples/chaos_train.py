@@ -27,9 +27,11 @@ from repro.models import model as M
 from repro.optim import optimizers as opt
 from repro.runtime.faults import FaultInjector, FaultProfile, RetryPolicy
 from repro.runtime.serve_loop import Request, ServeEngine, publish_banks
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fault", default="mixed",
                     choices=["drop", "delay", "corrupt", "duplicate", "nan",

@@ -17,10 +17,13 @@ from repro.configs.base import ColaConfig
 from repro.core import gl
 from repro.distributed import sharding as sh
 from repro.distributed import steps
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--data", type=int, default=4)
@@ -28,8 +31,7 @@ def main():
     ap.add_argument("--pods", type=int, default=2)
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((args.pods, args.data, args.model),
-                         ("pod", "data", "model"))
+    mesh = make_mesh(args.data, args.model, pods=args.pods)
     print(f"mesh: {dict(mesh.shape)} over {mesh.devices.size} devices")
 
     cfg = registry.reduced_config(args.arch)

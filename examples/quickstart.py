@@ -10,9 +10,11 @@ from repro.core.session import ColaSession
 from repro.data.pipeline import SyntheticLM
 from repro.models import model as M
 from repro.optim import optimizers as opt
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # a reduced smollm-family model that trains on CPU in seconds
     cfg = registry.reduced_config("smollm-135m").replace(n_layers=2)
     key = jax.random.PRNGKey(0)

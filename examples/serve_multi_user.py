@@ -14,9 +14,11 @@ from repro.data.pipeline import SyntheticLM
 from repro.models import model as M
 from repro.optim import optimizers as opt
 from repro.runtime.serve_loop import Request, ServeEngine
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = registry.reduced_config("smollm-135m").replace(n_layers=2)
     key = jax.random.PRNGKey(0)
     params = M.init(cfg, key)

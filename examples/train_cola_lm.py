@@ -10,6 +10,7 @@ model through the identical code path (default). Pass --full for the real
 smollm-135m config.
 """
 import argparse
+import os
 
 import jax
 
@@ -21,15 +22,18 @@ from repro.models import model as M
 from repro.optim import optimizers as opt
 from repro.optim import schedules
 from repro.runtime.train_loop import TrainLoop
-from repro.utils import human_count, tree_count
+from repro.utils import (REPO_ROOT, enable_compile_cache, human_count,
+                         tree_count)
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--full", action="store_true",
                     help="use the full smollm-135m (~135M params; slow on CPU)")
-    ap.add_argument("--workdir", default="/tmp/cola_lm_run")
+    ap.add_argument("--workdir",
+                    default=os.path.join(REPO_ROOT, "runs", "cola_lm"))
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--mode", default="faithful_offload",

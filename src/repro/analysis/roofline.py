@@ -5,8 +5,8 @@ Terms per (arch, mesh):
     memory term     = HLO_bytes / (chips * HBM_bw)
     collective term = collective_bytes / (chips * link_bw)
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment-specified).
+Hardware constants come from ``PEAKS``, keyed by the ``device_kind`` JAX
+reports; a kind missing from the table is an error, never a default.
 
 ``collective_bytes`` is parsed from the HLO text: the summed operand sizes of
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute ops.
@@ -16,9 +16,23 @@ from __future__ import annotations
 import re
 from typing import Any
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per link per chip
+# Published peaks of one chip, keyed by ``jax.devices()[i].device_kind``.
+# "TPU v5 lite" is the TPU v5e. Source: Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect per chip over 4 links (50 GB/s per link).
+PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """The chip's peak table entry; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {device_kind!r}"
+                         f" (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4,
@@ -130,15 +144,16 @@ def memory_record(mem) -> dict:
     return out
 
 
-def roofline_terms(rec: dict[str, Any]) -> dict[str, float]:
+def roofline_terms(rec: dict[str, Any], device_kind: str) -> dict[str, float]:
     """rec carries flops / bytes_accessed / collective_bytes of the PARTITIONED
     per-device module (verified by calibration: cost_analysis of the compiled
     SPMD executable reports one device's program; a 1024^3 matmul reports
     exactly 2*M*N*K). So the terms below are already per-chip — no division by
     the chip count."""
-    t_compute = rec["flops"] / PEAK_FLOPS
-    t_memory = rec["bytes_accessed"] / HBM_BW
-    t_coll = rec["collective_bytes"] / LINK_BW
+    pk = peaks(device_kind)
+    t_compute = rec["flops"] / pk["bf16_flops"]
+    t_memory = rec["bytes_accessed"] / pk["hbm_bw"]
+    t_coll = rec["collective_bytes"] / pk["link_bw"]
     terms = {"t_compute": t_compute, "t_memory": t_memory,
              "t_collective": t_coll}
     bottleneck = max(terms, key=terms.get)

@@ -58,6 +58,9 @@ class CollabSession:
         self.tm = telemetry if telemetry else None
         self.cfg, self.cc = cfg, cc
         self.base_params = params
+        # banks fitted on the offload devices are merged where the base
+        # weights live, so the server step stays on the server device
+        self._server_sharding = jax.tree.leaves(params)[0].sharding
         self.K = cc.users
         taps = gl.select_taps(cfg, cc.taps)
         # users may choose different adapter families (paper: LowRank-Linear)
@@ -95,10 +98,10 @@ class CollabSession:
         if self._merged_cache is None:
             p = self.base_params
             for k in range(self.K):
-                p = merge.merged_params(self.cfg, p,
-                                        self.user_specs[k].family_map,
-                                        self.offloaders[k].adapters,
-                                        self.cc.scale)
+                p = merge.merged_params(
+                    self.cfg, p, self.user_specs[k].family_map,
+                    jax.device_put(self.offloaders[k].adapters,
+                                   self._server_sharding), self.cc.scale)
             self._merged_cache = p
         return self._merged_cache
 

@@ -39,6 +39,10 @@ class ColaSession:
         self.tm = telemetry if telemetry else None
         self.cfg, self.cc = cfg, cc
         self.base_params = params
+        # the server step runs where the base weights live; adapters coming
+        # back from the offload device are placed there, or the next step
+        # would follow them to the offload device
+        self._server_sharding = jax.tree.leaves(params)[0].sharding
         self.optimizer = optimizer or optim_lib.adamw(lr)
         self.server_spec = gl.make_spec(cfg, cc)
         taps = gl.select_taps(cfg, cc.taps) if cc.mode != "ft" else ()
@@ -52,7 +56,7 @@ class ColaSession:
             self._step = jax.jit(self._ft_step)
             return
 
-        self.adapters = gl.init_adapters(cfg, cc, key)
+        self.adapters = self._on_server(gl.init_adapters(cfg, cc, key))
         if cc.mode in ("faithful_offload", "fused_fit"):
             self.offloader = Offloader(self.adapter_spec, self.adapters,
                                        self.optimizer, interval=cc.interval,
@@ -83,6 +87,9 @@ class ColaSession:
             return NULL_CONTEXT
         return self.tm.span("session.offload_round", cat="offload", tid=1,
                             user=ch.user, seq=ch._seq)
+
+    def _on_server(self, tree):
+        return jax.device_put(tree, self._server_sharding)
 
     # ------------------------------------------------------------------
     def _effective_params(self) -> dict:
@@ -118,7 +125,7 @@ class ColaSession:
                 self.channel.push(data)
                 new = self.channel.fit_round()
             if new is not None:
-                self.adapters = new
+                self.adapters = self._on_server(new)
                 self._merged_cache = None   # re-merge from pristine base
             return float(loss)
 
@@ -137,7 +144,7 @@ class ColaSession:
                     g, self.offloader.opt_state, self.offloader.adapters)
                 self.offloader.adapters = optim_lib.apply_updates(
                     self.offloader.adapters, updates)
-                self.adapters = self.offloader.adapters
+                self.adapters = self._on_server(self.offloader.adapters)
                 self._grad_accum = None
             return float(loss)
 
@@ -155,7 +162,7 @@ class ColaSession:
         ch = getattr(self, "channel", None)
         if ch is not None:
             ch.reset()
-            self.adapters = ch.adapters
+            self.adapters = self._on_server(ch.adapters)
             self._merged_cache = None
 
     def channel_health(self) -> dict:

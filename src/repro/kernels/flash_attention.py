@@ -10,7 +10,12 @@ TPU adaptation (vs the CUDA flash-attention the literature assumes):
   VMEM scratch across iterations — the systolic analogue of warp-level
   accumulation;
 - GQA is handled by the index_map (q-head h reads kv-head h // G), so kv tiles
-  are never physically repeated.
+  are never physically repeated;
+- the kernels run head-major: the wrapper transposes (B, S, H, Dh) to
+  (B, H, S, Dh) so every block's last two dims are (seq tile, Dh), which the
+  TPU block rule (multiples of (8, 128) or the whole axis) accepts for any
+  head count. The per-row softmax statistics travel as (B, H, S, 1) columns
+  for the same reason.
 
 Oracle: repro.kernels.ref.sdpa (uniform positions). Tests sweep shapes/dtypes
 in interpret mode.
@@ -70,9 +75,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)           # (Bq, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (Bk, Dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                 # (Bq, Dh)
+    k = k_ref[0, 0].astype(jnp.float32)                 # (Bk, Dh)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (Bq, Bk)
     if softcap is not None and softcap > 0:
@@ -104,21 +109,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     def _final():
         l = l_ref[...]
         safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0, :, 0, :] = (acc_ref[...] / safe_l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(
-            l > 0, m_ref[...] + jnp.log(safe_l), NEG_INF)
+        o_ref[0, 0] = (acc_ref[...] / safe_l[:, None]).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(
+            l > 0, m_ref[...] + jnp.log(safe_l), NEG_INF)[:, None]
 
 
 def _fwd(q, k, v, *, scale, causal, window, softcap, q_offset, interpret):
-    B, Sq, H, Dh = q.shape
-    _, Sk, K, _ = k.shape
+    """Head-major operands: q (B, H, Sq, Dh), k/v (B, K, Sk, Dh). Returns o
+    (B, H, Sq, Dh) and the row log-sum-exp (B, H, Sq, 1)."""
+    B, H, Sq, Dh = q.shape
+    K, Sk = k.shape[1], k.shape[2]
     G = H // K
     bq, bk = _block_q(Sq), _block_k(Sk)
     grid = (B, H, Sq // bq, Sk // bk)
 
     out_shapes = [
-        jax.ShapeDtypeStruct((B, Sq, H, Dh), q.dtype),
-        jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
+        jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -126,13 +133,13 @@ def _fwd(q, k, v, *, scale, causal, window, softcap, q_offset, interpret):
                           block_k=bk, n_kv=K, q_offset=q_offset),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=out_shapes,
         scratch_shapes=[
@@ -159,12 +166,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
-    lse = lse_ref[0, 0, :]
-    delta = delta_ref[0, 0, :]
+    q = q_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)
+    lse = lse_ref[0, 0][:, 0]
+    delta = delta_ref[0, 0][:, 0]
 
     s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     if softcap is not None and softcap > 0:
@@ -194,7 +201,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _final():
-        dq_ref[0, :, 0, :] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -208,12 +215,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
-    lse = lse_ref[0, 0, :]
-    delta = delta_ref[0, 0, :]
+    q = q_ref[0, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)
+    lse = lse_ref[0, 0][:, 0]
+    delta = delta_ref[0, 0][:, 0]
 
     s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     if softcap is not None and softcap > 0:
@@ -244,19 +251,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _final():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd(res, g, *, scale, causal, window, softcap, q_offset, interpret):
     q, k, v, o, lse = res
     do = g
-    B, Sq, H, Dh = q.shape
-    _, Sk, K, _ = k.shape
+    B, H, Sq, Dh = q.shape
+    K, Sk = k.shape[1], k.shape[2]
     G = H // K
     bq, bk = _block_q(Sq), _block_k(Sk)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = delta.transpose(0, 2, 1)                     # (B, H, Sq)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)                       # (B, H, Sq, 1)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -264,14 +271,14 @@ def _bwd(res, g, *, scale, causal, window, softcap, q_offset, interpret):
                           block_k=bk, q_offset=q_offset),
         grid=(B, H, Sq // bq, Sk // bk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Dh), lambda b, h, qi, ki: (b, qi, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, Dh), jnp.float32)],
         interpret=interpret,
@@ -285,20 +292,20 @@ def _bwd(res, g, *, scale, causal, window, softcap, q_offset, interpret):
                           block_k=bk, q_offset=q_offset),
         grid=(B, H, Sk // bk, Sq // bq),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, ki, qi: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, ki, qi: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, ki, qi: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, ki, qi: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, ki, qi: (b, h, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, ki, qi: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, ki, qi: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, ki, qi: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, ki, qi: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, ki, qi: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, ki, qi: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, ki, qi: (b, h, qi, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, ki, qi: (b, ki, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, ki, qi: (b, ki, h, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, ki, qi: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, bk, Dh), lambda b, h, ki, qi: (b, h, ki, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, H, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, H, Dh), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk, Dh), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sk, Dh), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, Dh), jnp.float32),
@@ -306,8 +313,8 @@ def _bwd(res, g, *, scale, causal, window, softcap, q_offset, interpret):
         ],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-    dk = dkh.reshape(B, Sk, K, G, Dh).sum(axis=3).astype(k.dtype)
-    dv = dvh.reshape(B, Sk, K, G, Dh).sum(axis=3).astype(v.dtype)
+    dk = dkh.reshape(B, K, G, Sk, Dh).sum(axis=2).astype(k.dtype)
+    dv = dvh.reshape(B, K, G, Sk, Dh).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -342,8 +349,11 @@ def flash_attention(q: Array, k: Array, v: Array, *, q_positions=None,
                     window: int | None = None, softcap: float | None = None,
                     scale: float | None = None, q_offset: int = 0,
                     interpret: bool = False) -> Array:
-    """Positions are assumed uniform (q starts at q_offset, kv at 0); the ref
-    oracle handles arbitrary per-row positions (continuous batching decode)."""
+    """q: (B, Sq, H, Dh), k/v: (B, Sk, K, Dh) -> (B, Sq, H, Dh). Positions are
+    assumed uniform (q starts at q_offset, kv at 0); the ref oracle handles
+    arbitrary per-row positions (continuous batching decode)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _flash(q, k, v, scale, causal, window, softcap, q_offset, interpret)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))   # head-major
+    o = _flash(q, k, v, scale, causal, window, softcap, q_offset, interpret)
+    return o.transpose(0, 2, 1, 3)

@@ -61,6 +61,12 @@ def pad_tokens(x: Array, idx: Array, align: int = PAD_ALIGN):
     return xp, ip
 
 
+def _column(idx: Array) -> Array:
+    """Token -> user ids as a (T, 1) column: its (block_t, 1) tiles meet the
+    TPU block rule, and the in-kernel row mask broadcasts across lanes."""
+    return idx.astype(jnp.int32)[:, None]
+
+
 def _kernel(x_ref, a_ref, b_ref, idx_ref, y_ref, acc_ref, *, scale, block_t):
     ui = pl.program_id(1)
 
@@ -71,11 +77,11 @@ def _kernel(x_ref, a_ref, b_ref, idx_ref, y_ref, acc_ref, *, scale, block_t):
     x = x_ref[...].astype(jnp.float32)          # (Bt, d_in)
     a = a_ref[0].astype(jnp.float32)            # (d_in, r)
     b = b_ref[0].astype(jnp.float32)            # (r, d_out)
-    idx = idx_ref[...]                          # (Bt,)
+    idx = idx_ref[...]                          # (Bt, 1)
 
     xa = jax.lax.dot_general(x, a, (((1,), (0,)), ((), ())))
     y = jax.lax.dot_general(xa, b, (((1,), (0,)), ((), ())))
-    m = (idx == ui).astype(jnp.float32)[:, None]
+    m = (idx == ui).astype(jnp.float32)
     acc_ref[...] += y * m
 
     @pl.when(ui == pl.num_programs(1) - 1)
@@ -96,13 +102,13 @@ def multi_lora(x: Array, A: Array, B: Array, idx: Array, *, scale: float = 1.0,
             pl.BlockSpec((bt, d_in), lambda t, u: (t, 0)),
             pl.BlockSpec((1, d_in, r), lambda t, u: (u, 0, 0)),
             pl.BlockSpec((1, r, d_out), lambda t, u: (u, 0, 0)),
-            pl.BlockSpec((bt,), lambda t, u: (t,)),
+            pl.BlockSpec((bt, 1), lambda t, u: (t, 0)),
         ],
         out_specs=pl.BlockSpec((bt, d_out), lambda t, u: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d_out), jnp.float32)],
         interpret=interpret,
-    )(x, A, B, idx.astype(jnp.int32))
+    )(x, A, B, _column(idx))
     return y
 
 
@@ -190,7 +196,7 @@ def _q8_kernel(x_ref, aq_ref, as_ref, bq_ref, bs_ref, idx_ref, y_ref, acc_ref,
 
     xa = jax.lax.dot_general(x, a, (((1,), (0,)), ((), ())))
     y = jax.lax.dot_general(xa, b, (((1,), (0,)), ((), ())))
-    m = (idx == ui).astype(jnp.float32)[:, None]
+    m = (idx == ui).astype(jnp.float32)
     acc_ref[...] += y * m
 
     @pl.when(ui == pl.num_programs(1) - 1)
@@ -218,11 +224,11 @@ def multi_lora_q8(x: Array, A_q: Array, A_scale: Array, B_q: Array,
             pl.BlockSpec((1, d_in, 1), lambda t, u: (u, 0, 0)),
             pl.BlockSpec((1, r, d_out), lambda t, u: (u, 0, 0)),
             pl.BlockSpec((1, r, 1), lambda t, u: (u, 0, 0)),
-            pl.BlockSpec((bt,), lambda t, u: (t,)),
+            pl.BlockSpec((bt, 1), lambda t, u: (t, 0)),
         ],
         out_specs=pl.BlockSpec((bt, d_out), lambda t, u: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d_out), jnp.float32)],
         interpret=interpret,
-    )(x, A_q, A_scale, B_q, B_scale, idx.astype(jnp.int32))
+    )(x, A_q, A_scale, B_q, B_scale, _column(idx))
     return y

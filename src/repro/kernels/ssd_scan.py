@@ -3,10 +3,8 @@
 The sequence is split into chunks of length ``chunk``; each chunk applies the
 quadratic masked form (``ref.ssd``) locally and carries the (H, P, N) state
 across chunks with ``lax.scan``. On TPU the per-chunk quadratic form is dense
-MXU work; the scan carries only the small state in registers/VMEM.
-
-``ssd_chunk_pallas`` is the Pallas intra-chunk kernel (TPU target) used when the
-backend requests it; the jnp chunked path is the oracle-equivalent default.
+MXU work; the scan carries only the small state in registers/VMEM. There is
+no Pallas kernel for it: this jnp path runs on every platform.
 """
 from __future__ import annotations
 
@@ -20,8 +18,8 @@ Array = jax.Array
 
 
 def ssd_chunked(x: Array, dt: Array, a: Array, B: Array, C: Array, D: Array,
-                init_state: Array | None = None, *, chunk: int = 128,
-                backend: str = "ref") -> tuple[Array, Array]:
+                init_state: Array | None = None, *, chunk: int = 128
+                ) -> tuple[Array, Array]:
     """Linear-time chunked scan; exact for any S.
 
     A non-divisible tail is handled as one exact-length ``ref.ssd`` call seeded

@@ -31,6 +31,9 @@ from repro.distributed import steps
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_lib
 
+# the chip the dry-run's roofline terms are priced against (roofline.PEAKS)
+TARGET_KIND = "TPU v5 lite"
+
 
 def _compile_cell(cfg, spec, mesh, cc):
     if spec.kind == "train":
@@ -209,7 +212,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     }
     peak = rec["memory"].get("peak_bytes_per_device", 0)
     rec["memory"]["peak_corrected_tpu"] = max(0, peak - emu)
-    rec.update(roofline.roofline_terms(rec))
+    rec.update(roofline.roofline_terms(rec, TARGET_KIND))
     rec["model_flops"] = roofline.model_flops(cfg, spec)
     # cost_analysis flops are per-device; model_flops is global
     rec["useful_ratio"] = (rec["model_flops"] / (rec["flops"] * rec["devices"])

@@ -518,6 +518,10 @@ class ServeEngine:
         if set(adapters) != set(self.bank):
             self.stats["bank_rejected"] += 1   # wrong tap set for this bank
             return False
+        # a bank fitted on the offload device joins the serving bank on the
+        # serving device (else the updated bank, and decode, would move)
+        adapters = jax.device_put(
+            adapters, jax.tree.leaves(self.bank)[0].sharding)
         new_bank = {}
         for tap, entry in self.bank.items():
             new_entry = dict(entry)

@@ -111,9 +111,9 @@ class TrainLoop:
             ad = jax.tree.map(jax.numpy.asarray, tree["adapters"])
             self.session.adapters = ad
             if hasattr(self.session, "offloader"):
-                self.session.offloader.adapters = ad
-                self.session.offloader.opt_state = jax.tree.map(
-                    jax.numpy.asarray, tree["opt_state"])
+                off = self.session.offloader
+                off.adapters = jax.device_put(ad, off.device)
+                off.opt_state = jax.device_put(tree["opt_state"], off.device)
             elif hasattr(self.session, "opt_state"):
                 self.session.opt_state = jax.tree.map(
                     jax.numpy.asarray, tree["opt_state"])

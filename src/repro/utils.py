@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 from typing import Any, Callable, Iterable, Mapping
 
 import jax
@@ -10,6 +11,26 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache (entry points only)
+# ---------------------------------------------------------------------------
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a script's process and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already uses that directory and nothing is set here. Otherwise the cache
+    lives at the fixed in-checkout path ``<repo>/.jax_cache``, so a later run
+    of the same checkout finds what an earlier one compiled."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

@@ -68,8 +68,9 @@ def test_multipod_mesh_and_serve_step():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import registry
         from repro.distributed import sharding as sh, steps
+        from repro.launch.mesh import make_mesh
         from repro.models import model as M
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = make_mesh(2, 2, pods=2)
         cfg = registry.reduced_config('mistral-nemo-12b').replace(
             n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
             d_ff=128, vocab_size=128)
@@ -99,7 +100,8 @@ def test_param_shardings_divisibility():
         import jax
         from repro.configs import registry
         from repro.distributed import sharding as sh, steps
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(2, 4)
         for arch in registry.ASSIGNED:
             cfg = registry.get_config(arch)
             shapes = steps.shaped_params(cfg)

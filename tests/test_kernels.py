@@ -165,7 +165,7 @@ def test_ops_sdpa_decode_backend_switch():
     try:
         b = ops.sdpa_decode(q, k, v, pos)
     finally:
-        ops.set_backend("ref")
+        ops.set_backend(None)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-5)
 
@@ -254,7 +254,7 @@ def test_ops_multi_lora_routes_grouped_when_bank_exceeds_batch():
     try:
         got = ops.multi_lora(x, A, B, idx)
     finally:
-        ops.set_backend("ref")
+        ops.set_backend(None)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
@@ -399,6 +399,37 @@ def test_ops_backend_switch():
     try:
         b = ops.sdpa(q, k, v, q_positions=pos, kv_positions=pos)
     finally:
-        ops.set_backend("ref")
+        ops.set_backend(None)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-5)
+
+
+def test_platform_routing_counts_fallbacks(monkeypatch):
+    """With no override, a process whose default device is a TPU routes a
+    supported shape through ``platform_dependent`` (whose CPU branch is the
+    oracle, so a CPU lowering here must equal ref) and counts every refused
+    shape once per trace, by op and shape."""
+    key = jax.random.PRNGKey(15)
+    q, k, v, pos = _decode_case(key, B=3, Smax=128, H=4, K=2, D=64)
+    want = ref.sdpa_decode(q, k, v, pos)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    ops.reset_fallbacks()
+    try:
+        got = jax.jit(ops.sdpa_decode)(q, k, v, pos)
+        assert ops.fallbacks() == {}
+        # a 2-row chunk (Sq > 1) has no decode kernel: counted, then oracle
+        q2 = jnp.concatenate([q, q], axis=1)
+        chunk = jax.jit(ops.sdpa_decode)(q2, k, v, pos)
+        fb = ops.fallbacks()
+    finally:
+        ops.reset_fallbacks()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(chunk),
+                               np.asarray(ref.sdpa_decode(q2, k, v, pos)),
+                               rtol=1e-6, atol=1e-6)
+    assert list(fb.values()) == [1]
+    (op, shapes), = fb
+    assert op == "decode_attention"
+    assert shapes[:2] == (("float32", (3, 2, 4, 64)),
+                          ("float32", (3, 128, 2, 64)))

@@ -90,7 +90,8 @@ def test_elastic_restore_new_topology(tmp_path):
     tree = {"w": jnp.arange(64.0).reshape(8, 8)}
     cm.save(1, tree)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(1, 1)
     sh = {"w": NamedSharding(mesh, P("data", "model"))}
     _, back = cm.restore(shardings=sh)
     np.testing.assert_array_equal(np.asarray(back["w"]), np.asarray(tree["w"]))
@@ -216,3 +217,21 @@ def test_watchdog_heartbeat_survives_disk_errors(tmp_path):
         assert dt >= 0.0
     assert bad.stats == {"steps": 3, "heartbeats": 0,
                          "heartbeat_failures": 3}
+
+
+# ---------------------------------------------------------------------------
+# roofline peak table
+# ---------------------------------------------------------------------------
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the table by JAX's device_kind; an unknown kind is an
+    error, never a silent v5e default."""
+    from repro.analysis import roofline
+    pk = roofline.peaks("TPU v5 lite")
+    assert pk["bf16_flops"] == 197e12 and pk["hbm_bw"] == 819e9
+    terms = roofline.roofline_terms(
+        {"flops": 197e12, "bytes_accessed": 819e9 / 2, "collective_bytes": 0},
+        "TPU v5 lite")
+    assert terms["t_compute"] == 1.0 and terms["bottleneck"] == "compute"
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("cpu")

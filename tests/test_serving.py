@@ -454,5 +454,5 @@ def test_decode_tokens_identical_across_kernel_backends():
     try:
         o_int, _ = _run_engine(cfg, params, banks, prompts, max_new=5)
     finally:
-        ops.set_backend("ref")
+        ops.set_backend(None)
     assert o_ref == o_int

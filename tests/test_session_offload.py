@@ -223,3 +223,69 @@ def test_collab_session_runs_and_merges():
                                       data_u.batch_at(9).items()
                                       if k != "user_id"})
     assert np.isfinite(float(loss))
+
+
+def test_adapters_come_back_to_the_server_device():
+    """With the offload device apart from the server device (here two host
+    devices), what the offload device fits is placed back where the base
+    weights live — in Mode A (merged and not), Mode B, a collaborative
+    session and a serving bank install — so the next server step never
+    follows the adapters to the offload device."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+        import jax
+        from repro.configs import registry
+        from repro.configs.base import ColaConfig
+        from repro.core.collab import CollabSession
+        from repro.core.session import ColaSession
+        from repro.data.pipeline import SyntheticLM
+        from repro.models import model as M
+        from repro.runtime.serve_loop import ServeEngine
+
+        server, offload = jax.devices()
+        cfg = registry.reduced_config('smollm-135m').replace(
+            n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
+            d_ff=128, vocab_size=128)
+        key = jax.random.PRNGKey(0)
+        params = jax.device_put(M.init(cfg, key), server)
+        data = SyntheticLM(cfg, batch=4, seq=16, seed=1)
+
+        def on(tree):
+            return {d for l in jax.tree.leaves(tree) for d in l.devices()}
+
+        for mode, merged in (('faithful_offload', False),
+                             ('faithful_offload', True), ('fused_fit', False)):
+            cc = ColaConfig(mode=mode, merged=merged, family='lowrank',
+                            taps='qv', rank=4)
+            sess = ColaSession(cfg, cc, params, key, offload_device=offload)
+            for t in range(2):
+                sess.step(data.batch_at(t))
+            assert on(sess.offloader.adapters) == {offload}, mode
+            assert on(sess.adapters) == {server}, (mode, on(sess.adapters))
+            assert on(sess._effective_params()) == {server}, (mode, merged)
+
+        cc = ColaConfig(mode='faithful_offload', merged=True, users=2,
+                        family='lowrank', taps='qv', rank=4)
+        col = CollabSession(cfg, cc, params, key)
+        for off in col.offloaders:
+            off.adapters = jax.device_put(off.adapters, offload)
+        col._merged_cache = None
+        assert on(col.merged_model()) == {server}
+
+        eng = ServeEngine(cfg, params, slots=2, max_len=32,
+                          user_adapters=[sess.adapters, sess.adapters])
+        assert eng.install_adapters(1, sess.offloader.adapters, 1)
+        assert on(eng.bank) == {server}, on(eng.bank)
+        print('OK placement')
+    """)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "OK placement" in out.stdout
