@@ -22,7 +22,7 @@ import numpy as np
 from repro.core import gl
 from repro.core.taps import ColaSpec
 from repro.optim import optimizers as optim_lib
-from repro.telemetry import annotate
+from repro.telemetry import span
 
 Array = jax.Array
 
@@ -126,7 +126,7 @@ class Offloader:
         if not self.ready:
             return None
         data = self._materialise()
-        with annotate("offload.fit"):
+        with span("offload.fit"):
             self.adapters, self.opt_state, _ = self._fit(
                 self.adapters, self.opt_state, data)
         self.buffers.clear()

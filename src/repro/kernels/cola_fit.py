@@ -100,5 +100,7 @@ def _pallas_fit(x, grad_h, A, B, *, scale, bt, grid, d_in, d_out, r,
             pltpu.VMEM((r, d_out), jnp.float32),
         ],
         interpret=interpret,
+        name="cola_fit",
+        metadata={"kernel": "cola_fit"},
     )(x, grad_h, A, B)
     return dA, dB

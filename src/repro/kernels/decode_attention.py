@@ -165,6 +165,8 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, Dh), q.dtype),
         interpret=interpret,
+        name="decode_attention",
+        metadata={"kernel": "decode_attention"},
     )(positions.astype(jnp.int32), live.astype(jnp.int32), qg,
       k_cache.reshape(B, Sk, K * Dh), v_cache.reshape(B, Sk, K * Dh))
     return o.reshape(B, 1, H, Dh)
@@ -230,6 +232,8 @@ def decode_attention_paged(q: Array, k_pool: Array, v_pool: Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, Dh), q.dtype),
         interpret=interpret,
+        name="decode_attention_paged",
+        metadata={"kernel": "decode_attention_paged"},
     )(positions.astype(jnp.int32), live.astype(jnp.int32),
       block_table.astype(jnp.int32), qg,
       k_pool.reshape(n_blocks, bs, K * Dh), v_pool.reshape(n_blocks, bs, K * Dh))

@@ -148,6 +148,8 @@ def _fwd(q, k, v, *, scale, causal, window, softcap, q_offset, interpret):
             pltpu.VMEM((bq,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
+        metadata={"kernel": "flash_attention_fwd"},
     )(q, k, v)
     return o, lse
 
@@ -282,6 +284,8 @@ def _bwd(res, g, *, scale, causal, window, softcap, q_offset, interpret):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, Dh), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
+        metadata={"kernel": "flash_attention_bwd_dq"},
     )(q, k, v, do, lse, delta)
 
     # dk/dv accumulate over q-heads within each kv group: run per q-head and
@@ -312,6 +316,8 @@ def _bwd(res, g, *, scale, causal, window, softcap, q_offset, interpret):
             pltpu.VMEM((bk, Dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
+        metadata={"kernel": "flash_attention_bwd_dkv"},
     )(q, k, v, do, lse, delta)
     dk = dkh.reshape(B, K, G, Sk, Dh).sum(axis=2).astype(k.dtype)
     dv = dvh.reshape(B, K, G, Sk, Dh).sum(axis=2).astype(v.dtype)

@@ -108,6 +108,8 @@ def multi_lora(x: Array, A: Array, B: Array, idx: Array, *, scale: float = 1.0,
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d_out), jnp.float32)],
         interpret=interpret,
+        name="multi_lora",
+        metadata={"kernel": "multi_lora"},
     )(x, A, B, _column(idx))
     return y
 
@@ -230,5 +232,7 @@ def multi_lora_q8(x: Array, A_q: Array, A_scale: Array, B_q: Array,
         out_shape=jax.ShapeDtypeStruct((T, d_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d_out), jnp.float32)],
         interpret=interpret,
+        name="multi_lora_q8",
+        metadata={"kernel": "multi_lora_q8"},
     )(x, A_q, A_scale, B_q, B_scale, _column(idx))
     return y

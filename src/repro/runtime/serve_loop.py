@@ -72,7 +72,7 @@ from repro.core import taps as taps_lib
 from repro.models import model as model_lib
 from repro.runtime.adapter_store import AdapterStore
 from repro.runtime.kv_pager import BlockPager
-from repro.telemetry import NULL_CONTEXT, annotate
+from repro.telemetry import span
 from repro.telemetry.metrics import NULL_METRIC, percentiles
 
 Array = jax.Array
@@ -229,6 +229,7 @@ class ServeEngine:
         # telemetry-on vs. off (guarded by tests/test_telemetry.py). The
         # disabled path is `self.tm is None` checks plus NULL_METRIC no-ops.
         self.tm = telemetry if telemetry else None
+        self._tracer = self.tm.tracer if self.tm else None
         _reg = self.tm.registry if self.tm else None
         _hist = (_reg.histogram if _reg is not None
                  else (lambda name: NULL_METRIC))
@@ -331,13 +332,6 @@ class ServeEngine:
                       "store_resident_bytes": 0, "store_fetch_time": 0.0}
 
     # -- telemetry ---------------------------------------------------------
-    def _span(self, name: str, **args):
-        """A serve-lane trace span, or the shared null context when tracing
-        is off — cheap enough to leave inline in the tick path."""
-        if self.tm is None:
-            return NULL_CONTEXT
-        return self.tm.span(name, cat="serve", tid=0, **args)
-
     def _record(self, scope: str, key, kind: str, **fields) -> None:
         if self.tm is not None:
             self.tm.record(scope, key, kind, **fields)
@@ -484,7 +478,8 @@ class ServeEngine:
 
     # -- adapter bank lifecycle ---------------------------------------------
     def install_adapters(self, user: int, adapters: dict, version: int) -> bool:
-        with self._span("serve.bank_install", user=user, version=version):
+        with span("serve.bank_install", self._tracer, user=user,
+                  version=version):
             ok = self._install_adapters(user, adapters, version)
         self._record("user", user, "bank_install", version=version, ok=ok)
         return ok
@@ -601,14 +596,12 @@ class ServeEngine:
     def _table(self):
         return jnp.asarray(self.pager.table) if self.pager is not None else None
 
-    def _admit(self) -> None:
-        """Admit up to ``admit_batch`` waiting requests into free slots. The
-        unchunked batched path pads all admitted prompts to one (J, P) batch
-        and runs a single prefill forward; the reference path feeds tokens one
-        by one through the (live-masked) decode step; the chunked path only
-        assigns slots (and reserves KV blocks) — chunk rounds in subsequent
-        ticks stream the prompts in. All paths emit each request's first
-        generated token from the prompt's own logits, never a decode tick."""
+    def _admit(self) -> list[int]:
+        """Admit up to ``admit_batch`` waiting requests into free slots
+        (reserving KV blocks and pinning adapter rows); returns their slots.
+        The chunked path prefills nothing here — chunk rounds in subsequent
+        ticks stream the prompts in; the unchunked paths prefill the admitted
+        rows next (``_prefill_admitted``)."""
         admitted: list[int] = []
         now = time.perf_counter()
         for i in range(self.slots):
@@ -636,7 +629,7 @@ class ServeEngine:
             self.positions[i] = 0
             admitted.append(i)
         if not admitted:
-            return
+            return admitted
         if self.store is not None:
             # prefetch-on-admission: residency is ensured (host -> device
             # fetch on miss) before any prefill/decode touches these slots.
@@ -649,47 +642,76 @@ class ServeEngine:
             r = self.active[i]
             self._record("slot", i, "admit", rid=r.rid, user=r.user,
                          prompt_len=len(r.prompt))
-        if self.prefill_chunk is not None:
-            return   # chunk rounds (one per tick) do the prefill work
+        return admitted
+
+    def _run(self, program, args: tuple, **static) -> np.ndarray:
+        """Dispatch one run of a jitted ``program`` (whose arguments the
+        caller's ``serve.prep`` phase built and uploaded) and wait for the
+        tokens it returns: the ``serve.dispatch`` and ``serve.sync`` phases.
+        The cache the program returns replaces the engine's."""
+        with span("serve.dispatch", self._tracer):
+            out, self.cache = program(*args, **static)
+        with span("serve.sync", self._tracer):
+            return np.asarray(out)
+
+    def _prefill_admitted(self, admitted: list[int]) -> None:
+        """Unchunked prefill of the rows just admitted. The batched path pads
+        them to one (J, P) batch and runs a single prefill forward
+        (recurrent rows: one forward per row); the reference path feeds
+        tokens one by one through the (live-masked) decode step. All emit
+        each request's first generated token from the prompt's own logits,
+        never a decode tick."""
+        tr = self._tracer
         rows = [(i, np.asarray(self.active[i].prompt, np.int32))
                 for i in admitted]
         t0 = time.perf_counter()
-        if self.prefill_mode == "reference":
-            for i, feed in rows:
-                nxt = 0
+
+        def book(now: float) -> None:   # the admission's last emit
+            dt = now - t0
+            self.stats["prefill_time"] += dt
+            self._prefill_s.append(dt)
+            self._h_prefill_call.observe(dt)
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_tokens"] += sum(len(f) for _, f in rows)
+            for i, _ in rows:
+                if self.active[i] is not None:
+                    self._maybe_finish(i, now)
+            self._sync_store_stats()
+            self._sync_pager_stats()
+
+        if self.prefill_mode == "batched" and not self._recurrent:
+            with span("serve.prep", tr):
+                args = self._prefill_args(rows)
+            nxt = self._run(self._prefill, args)
+            with span("serve.emit", tr):
+                now = time.perf_counter()
+                for r, (i, _) in enumerate(rows):
+                    self._first_token(i, int(nxt[r]), now)
+                book(now)
+            return
+        for k, (i, feed) in enumerate(rows):
+            if self.prefill_mode == "reference":
                 for t, tok in enumerate(feed):
                     nxt = self._feed(i, int(tok), t)
-                self._first_token(i, nxt, time.perf_counter())
-        else:
-            with self._span("serve.prefill", rows=len(rows)):
-                self._prefill_batch(rows)
-        dt = time.perf_counter() - t0
-        self.stats["prefill_time"] += dt
-        self._prefill_s.append(dt)
-        self._h_prefill_call.observe(dt)
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_tokens"] += sum(len(f) for _, f in rows)
-        now = time.perf_counter()
-        for i, _ in rows:
-            if self.active[i] is not None:
-                self._maybe_finish(i, now)
+            else:
+                # Recurrent (ssm/conv) state folds in every input token, so
+                # a right-padded batch would pollute shorter rows' state:
+                # prefill each row at its exact length (still one forward
+                # per prompt instead of one decode step per token).
+                with span("serve.prep", tr):
+                    args = (self.params, self._dispatch_bank(), self.cache,
+                            jnp.asarray(feed[None, :]),
+                            jnp.asarray(self._dispatch_idx()[i:i + 1]),
+                            jnp.asarray(np.array([i], np.int32)),
+                            jnp.asarray(np.array([len(feed)], np.int32)))
+                nxt = int(self._run(self._prefill, args)[0])
+            with span("serve.emit", tr):
+                now = time.perf_counter()
+                self._first_token(i, nxt, now)
+                if k == len(rows) - 1:
+                    book(now)
 
-    def _prefill_batch(self, rows: list[tuple[int, np.ndarray]]) -> None:
-        if self._recurrent:
-            # Recurrent (ssm/conv) state folds in every input token, so a
-            # right-padded batch would pollute shorter rows' state: prefill
-            # each row at its exact length (still one forward per prompt
-            # instead of one decode step per token).
-            for i, feed in rows:
-                nxt, self.cache = self._prefill(
-                    self.params, self._dispatch_bank(), self.cache,
-                    jnp.asarray(feed[None, :]),
-                    jnp.asarray(self._dispatch_idx()[i:i + 1]),
-                    jnp.asarray(np.array([i], np.int32)),
-                    jnp.asarray(np.array([len(feed)], np.int32)))
-                self._first_token(i, int(np.asarray(nxt)[0]),
-                                  time.perf_counter())
-            return
+    def _prefill_args(self, rows: list[tuple[int, np.ndarray]]) -> tuple:
         # attention KV: pad-token garbage beyond a row's true length is safe
         # (decode overwrites position p before attending; causality hides > p),
         # so bucket shapes to bound jit recompilation. The bucket never
@@ -706,15 +728,9 @@ class ServeEngine:
             users[r] = self._dispatch_idx()[i]
             slot_ids[r] = i
             lengths[r] = len(feed)
-        nxt, self.cache = self._prefill(self.params, self._dispatch_bank(),
-                                        self.cache, jnp.asarray(toks),
-                                        jnp.asarray(users),
-                                        jnp.asarray(slot_ids),
-                                        jnp.asarray(lengths))
-        nxt = np.asarray(nxt)
-        now = time.perf_counter()
-        for r, (i, _) in enumerate(rows):
-            self._first_token(i, int(nxt[r]), now)
+        return (self.params, self._dispatch_bank(), self.cache,
+                jnp.asarray(toks), jnp.asarray(users), jnp.asarray(slot_ids),
+                jnp.asarray(lengths))
 
     def _feed(self, slot: int, token: int, pos: int) -> int:
         """Reference single-row prefill step: decode one prompt token into one
@@ -722,18 +738,17 @@ class ServeEngine:
         the request's first generated token). The live mask confines the cache
         write to ``slot`` (the unmasked version corrupted position 0 of every
         other live slot)."""
-        toks = np.zeros((self.slots, 1), np.int32)
-        toks[slot, 0] = token
-        positions = np.zeros((self.slots,), np.int32)
-        positions[slot] = pos
-        live = np.zeros((self.slots,), bool)
-        live[slot] = True
-        nxt, self.cache = self._decode(self.params, self._dispatch_bank(),
-                                       self.cache, None, jnp.asarray(toks),
-                                       jnp.asarray(positions),
-                                       jnp.asarray(self._dispatch_idx()),
-                                       jnp.asarray(live))
-        return int(np.asarray(nxt)[slot])
+        with span("serve.prep", self._tracer):
+            toks = np.zeros((self.slots, 1), np.int32)
+            toks[slot, 0] = token
+            positions = np.zeros((self.slots,), np.int32)
+            positions[slot] = pos
+            live = np.zeros((self.slots,), bool)
+            live[slot] = True
+            args = (self.params, self._dispatch_bank(), self.cache, None,
+                    jnp.asarray(toks), jnp.asarray(positions),
+                    jnp.asarray(self._dispatch_idx()), jnp.asarray(live))
+        return int(self._run(self._decode, args)[slot])
 
     def _first_token(self, i: int, tok: int, now: float) -> None:
         """Record a request's first generated token (emitted from its prompt's
@@ -796,43 +811,50 @@ class ServeEngine:
             todo = sorted(groups.items())
         else:
             todo = [(C, pend)]
-        for width, idx_list in todo:
-            toks = np.zeros((self.slots, width), np.int32)
-            lens = np.ones((self.slots,), np.int32)
-            live = np.zeros((self.slots,), bool)
-            pos = np.zeros((self.slots,), np.int32)
-            for i in idx_list:
-                req = self.active[i]
-                c = min(width, len(req.prompt) - req._consumed)
-                toks[i, :c] = req.prompt[req._consumed:req._consumed + c]
-                lens[i] = c
-                live[i] = True
-                pos[i] = req._consumed
-                if self.pager is not None:
-                    ok = self.pager.ensure(
-                        i, min(req._consumed + width - 1, self.max_len - 1))
-                    assert ok, "admission reservation must cover the prompt"
-            nxt, self.cache = self._chunk(
-                self.params, self._dispatch_bank(), self.cache, self._table(),
-                jnp.asarray(toks), jnp.asarray(pos),
-                jnp.asarray(self._dispatch_idx()), jnp.asarray(live),
-                jnp.asarray(lens))
-            nxt = np.asarray(nxt)
-            now = time.perf_counter()
-            for i in idx_list:
-                req = self.active[i]
-                c = min(width, len(req.prompt) - req._consumed)
-                req._consumed += c
-                self.stats["prefill_tokens"] += c
-                if req._consumed >= len(req.prompt):
-                    self._first_token(i, int(nxt[i]), now)
-                    self._maybe_finish(i, now)
-            self.stats["prefill_chunks"] += len(idx_list)
-        self.stats["chunk_rounds"] += 1
-        dt = time.perf_counter() - t0
-        self.stats["prefill_time"] += dt
-        self._prefill_s.append(dt)
-        self._h_prefill_chunk.observe(dt)
+        tr = self._tracer
+        for k, (width, idx_list) in enumerate(todo):
+            with span("serve.prep", tr):
+                toks = np.zeros((self.slots, width), np.int32)
+                lens = np.ones((self.slots,), np.int32)
+                live = np.zeros((self.slots,), bool)
+                pos = np.zeros((self.slots,), np.int32)
+                for i in idx_list:
+                    req = self.active[i]
+                    c = min(width, len(req.prompt) - req._consumed)
+                    toks[i, :c] = req.prompt[req._consumed:req._consumed + c]
+                    lens[i] = c
+                    live[i] = True
+                    pos[i] = req._consumed
+                    if self.pager is not None:
+                        ok = self.pager.ensure(
+                            i, min(req._consumed + width - 1,
+                                   self.max_len - 1))
+                        assert ok, ("admission reservation must cover the "
+                                    "prompt")
+                args = (self.params, self._dispatch_bank(), self.cache,
+                        self._table(), jnp.asarray(toks), jnp.asarray(pos),
+                        jnp.asarray(self._dispatch_idx()), jnp.asarray(live),
+                        jnp.asarray(lens))
+            nxt = self._run(self._chunk, args)
+            with span("serve.emit", tr):
+                now = time.perf_counter()
+                for i in idx_list:
+                    req = self.active[i]
+                    c = min(width, len(req.prompt) - req._consumed)
+                    req._consumed += c
+                    self.stats["prefill_tokens"] += c
+                    if req._consumed >= len(req.prompt):
+                        self._first_token(i, int(nxt[i]), now)
+                        self._maybe_finish(i, now)
+                self.stats["prefill_chunks"] += len(idx_list)
+                if k == len(todo) - 1:   # the round's last emit
+                    self.stats["chunk_rounds"] += 1
+                    dt = time.perf_counter() - t0
+                    self.stats["prefill_time"] += dt
+                    self._prefill_s.append(dt)
+                    self._h_prefill_chunk.observe(dt)
+                    self._sync_store_stats()
+                    self._sync_pager_stats()
         return pend
 
     def _burst_len(self, live_idx: list[int]) -> int:
@@ -860,78 +882,85 @@ class ServeEngine:
         fully in cache (or a burst when ``decode_burst`` allows; bursts are
         capped to 1 while any slot is prefilling so the chunk interleave — and
         with it decode latency — stays per-tick flat)."""
-        with self._span("serve.tick", tick=self.stats["ticks"]):
+        with span("serve.tick", self._tracer, tick=self.stats["ticks"]):
             return self._tick_inner()
 
     def _tick_inner(self) -> int:
+        # Every piece of host work runs inside one phase span (admit, and
+        # per program run prep / dispatch / sync / emit, see ``_run``);
+        # only the choice of what runs next lies between them.
+        tr = self._tracer
         if self.queue:
-            with self._span("serve.admit", queued=len(self.queue)):
-                self._admit()
+            with span("serve.admit", tr, queued=len(self.queue)):
+                admitted = self._admit()
+            if admitted and self.prefill_chunk is None:
+                with span("serve.prefill", tr, rows=len(admitted)):
+                    self._prefill_admitted(admitted)
         prefilling: list[int] = []
         if self.prefill_chunk is not None and any(
                 r is not None and r._consumed < len(r.prompt)
                 for r in self.active):
-            with self._span("serve.prefill_chunk"):
+            with span("serve.prefill_chunk", tr):
                 prefilling = self._chunk_round()
         live_idx = [i for i, r in enumerate(self.active)
                     if r is not None and r._consumed >= len(r.prompt)]
         if not live_idx:
             if prefilling:
                 self.stats["ticks"] += 1
+            else:   # no program ran: book what admission changed
+                with span("serve.emit", tr):
+                    self._sync_store_stats()
+                    self._sync_pager_stats()
+            return 0
+        n = 1 if prefilling else self._burst_len(live_idx)
+        with span("serve.decode", tr, live=len(live_idx), burst=n):
+            return self._decode_round(live_idx, n)
+
+    def _decode_round(self, live_idx: list[int], n: int) -> int:
+        """Decode ``n`` tokens (one, or a burst) for every live slot."""
+        tr = self._tracer
+        with span("serve.prep", tr):
+            toks = np.zeros((self.slots, 1), np.int32)
+            live = np.zeros((self.slots,), bool)
+            for i in live_idx:
+                toks[i, 0] = self.active[i]._last
+                live[i] = True
+            if self.pager is not None:
+                for i in live_idx:
+                    ok = self.pager.ensure(
+                        i, min(int(self.positions[i]) + n - 1,
+                               self.max_len - 1))
+                    assert ok, "admission reservation must cover the horizon"
+            args = (self.params, self._dispatch_bank(), self.cache,
+                    self._table(), jnp.asarray(toks),
+                    jnp.asarray(self.positions),
+                    jnp.asarray(self._dispatch_idx()), jnp.asarray(live))
+            t0 = time.perf_counter()
+        if n <= 1:
+            trace = self._run(self._decode, args)[None]       # (1, slots)
+        else:
+            trace = self._run(self._decode_n, args, n=n)      # (n, slots)
+        with span("serve.emit", tr):
+            now = time.perf_counter()
+            self.stats["decode_time"] += now - t0
+            # one sample per tick decoded: a burst's dispatch wall is split
+            # evenly so percentiles stay comparable across decode_burst
+            self._decode_tick_s.append((now - t0) / trace.shape[0])
+            self._h_decode_tick.observe((now - t0) / trace.shape[0])
+            for step in range(trace.shape[0]):
+                for i in live_idx:
+                    req = self.active[i]
+                    tok = int(trace[step, i])
+                    req.out.append(tok)
+                    req._last = tok
+                    self.positions[i] += 1
+            for i in live_idx:
+                self._maybe_finish(i, now)
+            self.stats["ticks"] += trace.shape[0]
+            self.stats["tokens"] += trace.shape[0] * len(live_idx)
+            self.stats["decode_tokens"] += trace.shape[0] * len(live_idx)
             self._sync_store_stats()
             self._sync_pager_stats()
-            return 0
-        toks = np.zeros((self.slots, 1), np.int32)
-        live = np.zeros((self.slots,), bool)
-        for i in live_idx:
-            toks[i, 0] = self.active[i]._last
-            live[i] = True
-        n = 1 if prefilling else self._burst_len(live_idx)
-        if self.pager is not None:
-            for i in live_idx:
-                ok = self.pager.ensure(
-                    i, min(int(self.positions[i]) + n - 1, self.max_len - 1))
-                assert ok, "admission reservation must cover the horizon"
-        bank = self._dispatch_bank()
-        idx = jnp.asarray(self._dispatch_idx())
-        table = self._table()
-        t0 = time.perf_counter()
-        with self._span("serve.decode", live=len(live_idx), burst=n), \
-                annotate("serve.decode"):
-            if n <= 1:
-                nxt, self.cache = self._decode(self.params, bank, self.cache,
-                                               table, jnp.asarray(toks),
-                                               jnp.asarray(self.positions),
-                                               idx, jnp.asarray(live))
-                trace = np.asarray(nxt)[None]                  # (1, slots)
-            else:
-                trace, self.cache = self._decode_n(self.params, bank,
-                                                   self.cache, table,
-                                                   jnp.asarray(toks),
-                                                   jnp.asarray(self.positions),
-                                                   idx, jnp.asarray(live),
-                                                   n=n)
-                trace = np.asarray(trace)                      # (n, slots)
-        now = time.perf_counter()
-        self.stats["decode_time"] += now - t0
-        # one sample per tick decoded: a burst's dispatch wall is split evenly
-        # so percentiles stay comparable across decode_burst settings
-        self._decode_tick_s.append((now - t0) / trace.shape[0])
-        self._h_decode_tick.observe((now - t0) / trace.shape[0])
-        for step in range(trace.shape[0]):
-            for i in live_idx:
-                req = self.active[i]
-                tok = int(trace[step, i])
-                req.out.append(tok)
-                req._last = tok
-                self.positions[i] += 1
-        for i in live_idx:
-            self._maybe_finish(i, now)
-        self.stats["ticks"] += trace.shape[0]
-        self.stats["tokens"] += trace.shape[0] * len(live_idx)
-        self.stats["decode_tokens"] += trace.shape[0] * len(live_idx)
-        self._sync_store_stats()
-        self._sync_pager_stats()
         return trace.shape[0] * len(live_idx)
 
     def run_until_idle(self, max_ticks: int = 10_000) -> None:

@@ -8,10 +8,13 @@ Three pillars (ISSUE 10):
   ``pager.*``, ``train.*``) with one ``snapshot()``, a JSONL streamer and a
   Prometheus text exporter. The five legacy stat dicts keep working and are
   absorbed into the registry.
-- **Tracing** (`tracing.Tracer`): Chrome-trace-event (Perfetto-loadable)
-  spans — per-tick serve spans and per-user offload-round spans carrying the
-  channel's seq ids in their args. Read back with
-  ``python -m repro.trace_summary``.
+- **Tracing** (``span``, `tracing.Tracer`): one span helper at every site.
+  It writes a Chrome-trace-event (Perfetto-loadable) span when a tracer is
+  attached — per-tick serve spans and per-user offload-round spans carrying
+  the channel's seq ids in their args, read back with
+  ``python -m repro.trace_summary`` — and a ``jax.profiler.TraceAnnotation``
+  of the same name when ``enable_jax_annotations(True)`` armed them, so the
+  spans land in the profiler's trace beside the device's ops.
 - **Flight recorder** (`recorder.FlightRecorder`): bounded per-user/per-slot
   rings of recent events, frozen into postmortem files on quarantine,
   validation rollback, PagerError or a watchdog straggler.
@@ -34,31 +37,49 @@ from repro.telemetry.recorder import FlightRecorder
 from repro.telemetry.tracing import Tracer, validate_trace
 
 __all__ = ["Telemetry", "MetricRegistry", "Tracer", "FlightRecorder",
-           "validate_trace", "percentiles", "annotate", "NULL_CONTEXT",
-           "DEFAULT_TIME_BUCKETS"]
+           "validate_trace", "percentiles", "span", "enable_jax_annotations",
+           "NULL_CONTEXT", "DEFAULT_TIME_BUCKETS"]
 
 # one shared reusable no-op context: the entire cost of a disabled span
 NULL_CONTEXT = contextlib.nullcontext()
 
-# module-global switch for jax-profiler annotations around jitted dispatches
-_ANNOTATE = False
+# ``jax.profiler.TraceAnnotation`` while profiler annotations are armed
+_ANNOTATION = None
 
 
 def enable_jax_annotations(on: bool) -> None:
-    global _ANNOTATE
-    _ANNOTATE = bool(on)
+    """Arm (or disarm) profiler annotations for every ``span`` in the
+    process, whether or not a ``Telemetry`` is attached to the component."""
+    global _ANNOTATION
+    if on:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    else:
+        _ANNOTATION = None
 
 
-def annotate(name: str):
-    """Optional ``jax.profiler.TraceAnnotation`` around a jitted hot-path
-    dispatch (decode tick, prefill chunk, offloaded fit). Off by default —
-    the disabled path returns the shared null context. Enable via
-    ``Telemetry(jax_annotations=True)`` when profiling with the jax/TensorBoard
-    profiler; the annotation names host dispatch slices in that timeline."""
-    if not _ANNOTATE:
-        return NULL_CONTEXT
-    from jax.profiler import TraceAnnotation
-    return TraceAnnotation(name)
+@contextlib.contextmanager
+def _nested(outer, inner):
+    with outer, inner:
+        yield
+
+
+def span(name: str, tracer: Tracer | None = None, *, cat: str = "serve",
+         tid: int = 0, **args):
+    """The stack's one span. Armed profiler annotations write a
+    ``jax.profiler.TraceAnnotation`` named ``name`` (on the profiler's
+    clock, read back from its trace); a ``tracer`` (``Telemetry.tracer``)
+    writes a Chrome-trace event with ``args`` on lane ``tid``; both when
+    both are on. With neither it returns the shared ``NULL_CONTEXT`` after
+    two checks."""
+    ann = _ANNOTATION
+    if ann is None:
+        if tracer is None:
+            return NULL_CONTEXT
+        return tracer.span(name, cat=cat, tid=tid, **args)
+    if tracer is None:
+        return ann(name)
+    return _nested(ann(name), tracer.span(name, cat=cat, tid=tid, **args))
 
 
 class Telemetry:
@@ -72,29 +93,26 @@ class Telemetry:
                         accumulate in memory until ``export_trace``).
     recorder_capacity : events retained per flight-recorder key.
     out_dir           : where postmortem files land (None = in-memory only).
-    jax_annotations   : arm ``annotate()`` hooks around jitted dispatches.
+
+    Profiler annotations are armed for the whole process, not per facade:
+    ``enable_jax_annotations(True)``.
     """
 
     def __init__(self, *, enabled: bool = True, trace: bool = False,
-                 recorder_capacity: int = 64, out_dir: str | None = None,
-                 jax_annotations: bool = False):
+                 recorder_capacity: int = 64, out_dir: str | None = None):
         self.enabled = bool(enabled)
         self.registry = MetricRegistry(enabled=self.enabled)
         self.tracer = Tracer() if (self.enabled and trace) else None
         self.recorder = (FlightRecorder(capacity=recorder_capacity,
                                         out_dir=out_dir)
                          if self.enabled else None)
-        if self.enabled and jax_annotations:
-            enable_jax_annotations(True)
 
     def __bool__(self) -> bool:
         return self.enabled
 
     # -- tracing -----------------------------------------------------------
     def span(self, name: str, cat: str = "serve", tid: int = 0, **args):
-        if self.tracer is None:
-            return NULL_CONTEXT
-        return self.tracer.span(name, cat=cat, tid=tid, **args)
+        return span(name, self.tracer, cat=cat, tid=tid, **args)
 
     def name_thread(self, tid: int, name: str) -> None:
         if self.tracer is not None:

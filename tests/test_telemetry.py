@@ -11,7 +11,10 @@ Acceptance invariants:
 - exported traces are valid Chrome-trace-event JSON (schema + per-lane span
   nesting), loadable in Perfetto and parsed by ``repro.trace_summary``;
 - the disabled path is zero-cost by construction: shared null context /
-  null metric singletons, no tracer, no recorder.
+  null metric singletons, no tracer, no recorder;
+- one span helper (``telemetry.span``) writes a profiler annotation when
+  annotations are armed and a Chrome-trace event when a tracer is attached,
+  and every engine tick's host work runs in its phase spans.
 
 The chaos-side acceptance (quarantine postmortem with failing seq ids) lives
 in tests/test_faults.py next to the rest of the chaos suite.
@@ -24,6 +27,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.configs import registry
 from repro.configs.base import ColaConfig
 from repro.core import gl
@@ -33,7 +37,7 @@ from repro.models import model as M
 from repro.optim import optimizers as opt
 from repro.runtime.serve_loop import Request, ServeEngine
 from repro.runtime.train_loop import TrainLoop
-from repro.telemetry import NULL_CONTEXT, Telemetry, validate_trace
+from repro.telemetry import NULL_CONTEXT, Telemetry, span, validate_trace
 from repro.telemetry.metrics import (NULL_METRIC, Histogram, MetricRegistry,
                                      percentiles)
 from repro.telemetry.recorder import FlightRecorder
@@ -46,6 +50,15 @@ def _tiny(name="smollm-135m", **over):
                 d_ff=128, vocab_size=128)
     base.update(over)
     return cfg.replace(**{k: v for k, v in base.items() if hasattr(cfg, k)})
+
+
+@pytest.fixture(autouse=True)
+def _disarmed():
+    """Each test starts and ends with profiler annotations disarmed (the
+    switch is process-wide, and a traced benchmark run arms it)."""
+    telemetry.enable_jax_annotations(False)
+    yield
+    telemetry.enable_jax_annotations(False)
 
 
 def _prompts(cfg, lens, seed=0):
@@ -269,8 +282,10 @@ def test_disabled_paths_share_null_singletons():
     cfg = _tiny()
     eng_none = ServeEngine(cfg, M.init(cfg, jax.random.PRNGKey(0)), slots=2,
                            max_len=32)
-    assert eng_none.tm is None
-    assert eng_none._span("serve.tick") is NULL_CONTEXT
+    assert eng_none.tm is None and eng_none._tracer is None
+    # the one span helper, disarmed and without a tracer
+    assert span("serve.tick", eng_none._tracer, tick=0) is NULL_CONTEXT
+    assert span("offload.fit") is NULL_CONTEXT
     assert eng_none._h_ttft is NULL_METRIC
     assert eng_none.telemetry_snapshot() == {}
     # enabled-without-trace still has no tracer: spans stay free
@@ -278,27 +293,77 @@ def test_disabled_paths_share_null_singletons():
     assert tm_plain and tm_plain.span("x") is NULL_CONTEXT
 
 
-def test_disabled_span_overhead_bounded():
+@pytest.mark.parametrize("site", ["facade", "helper"])
+def test_disabled_span_overhead_bounded(site):
     """100k disabled span entries must be pure-python cheap (no allocation,
     no syscalls) — an absolute wall bound, generous enough for shared CI."""
-    tm_off = Telemetry(enabled=False)
+    open_span = (Telemetry(enabled=False).span if site == "facade"
+                 else lambda name: span(name, None))
     t0 = time.perf_counter()
     for _ in range(100_000):
-        with tm_off.span("serve.tick"):
+        with open_span("serve.tick"):
             pass
     assert time.perf_counter() - t0 < 2.0
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records entries."""
+    opened: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.opened.append(self.name)
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("annotations", [False, True], ids=["off", "armed"])
+@pytest.mark.parametrize("traced", [False, True], ids=["no_tracer", "tracer"])
+def test_span_writes_to_what_is_on(monkeypatch, annotations, traced):
+    """One call site: a profiler annotation when armed, a Chrome-trace event
+    when a tracer is attached, both when both, the null context when
+    neither."""
+    monkeypatch.setattr(telemetry, "_ANNOTATION",
+                        _FakeAnnotation if annotations else None)
+    monkeypatch.setattr(_FakeAnnotation, "opened", [])
+    tracer = Tracer() if traced else None
+    ctx = span("serve.prep", tracer, tick=3)
+    if not (annotations or traced):
+        assert ctx is NULL_CONTEXT
+    with ctx:
+        pass
+    assert _FakeAnnotation.opened == (["serve.prep"] if annotations else [])
+    if traced:
+        (ev,) = tracer.events
+        assert ev["name"] == "serve.prep" and ev["args"] == {"tick": 3}
+
+
+def test_enable_jax_annotations_arms_the_profiler_annotation():
+    from jax.profiler import TraceAnnotation
+    telemetry.enable_jax_annotations(True)
+    assert isinstance(span("serve.tick"), TraceAnnotation)
+    telemetry.enable_jax_annotations(False)
+    assert span("serve.tick") is NULL_CONTEXT
 
 
 # ---------------------------------------------------------------------------
 # serve engine: bit-identity, counter consistency, tail latency
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("annotations", [False, True], ids=["off", "armed"])
 @pytest.mark.parametrize("name", sorted(SERVE_CASES))
-def test_tokens_bit_identical_telemetry_on_off(name, tmp_path):
+def test_tokens_bit_identical_telemetry_on_off(name, annotations, tmp_path):
     _, ref_outs = _serve(name, telemetry=None)
     tm = Telemetry(trace=True, out_dir=str(tmp_path))
+    telemetry.enable_jax_annotations(annotations)
     eng, outs = _serve(name, telemetry=tm)
     assert outs == ref_outs, "telemetry must never perturb generated tokens"
+    if annotations:
+        # annotations alone, no Telemetry attached (the benchmark's case)
+        assert _serve(name, telemetry=None)[1] == ref_outs
     # and the instrumented run actually observed the work
     snap = eng.telemetry_snapshot()
     assert snap["serve.completed"] == len(ref_outs)
@@ -371,6 +436,52 @@ def test_serve_trace_schema_and_summary(tmp_path):
     assert trace_summary.main([trace_path, "--metrics", snap_path]) == 0
     table = trace_summary.span_table(json.load(open(trace_path)))
     assert any(row["name"] == "serve.tick" for row in table)
+
+
+PHASES = ("serve.prep", "serve.dispatch", "serve.sync", "serve.emit")
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("smollm-135m", {}),
+    ("smollm-135m", dict(decode_burst=4)),
+    ("smollm-135m", dict(prefill_chunk=None, kv_layout="dense")),
+    ("smollm-135m", dict(prefill_chunk=None, kv_layout="dense",
+                         prefill_mode="reference")),
+    ("mamba2-370m", {}),
+], ids=["chunked_paged", "burst", "unchunked", "reference", "ssm"])
+def test_tick_phases_nest_in_ticks(name, kw):
+    """Each program run of a tick shows as prep, dispatch and sync, once
+    each, and an emit, inside ``serve.tick``; admission runs in
+    ``serve.admit``."""
+    tm = Telemetry(trace=True)
+    eng, _ = _serve(name, telemetry=tm, **kw)
+    doc = tm.tracer.to_doc()
+    assert validate_trace(doc) == []
+    spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    ticks = [(e["ts"], e["ts"] + e["dur"]) for e in spans
+             if e["name"] == "serve.tick"]
+    count = {n: 0 for n in PHASES + ("serve.admit",)}
+    for e in spans:
+        if e["name"] in count:
+            count[e["name"]] += 1
+            assert any(a <= e["ts"] and e["ts"] + e["dur"] <= b
+                       for a, b in ticks), f"{e['name']} outside a tick"
+    runs = count["serve.prep"]
+    assert runs and count["serve.dispatch"] == count["serve.sync"] == runs
+    assert count["serve.admit"] >= 1
+    # the reference path feeds a prompt token by token and emits once, at
+    # the prompt's last token; every other run emits what it made
+    assert count["serve.emit"] >= (1 if eng.prefill_mode == "reference"
+                                   else runs)
+    decodes = sum(e["name"] == "serve.decode" for e in spans)
+    if eng._recurrent:
+        # one run per chunk group: a round groups its rows by chunk width
+        assert (decodes + eng.stats["chunk_rounds"] <= runs
+                <= decodes + eng.stats["prefill_chunks"])
+    elif eng.prefill_mode == "batched":
+        # one run per decode round and per chunk round or admission prefill
+        assert runs == decodes + eng.stats["chunk_rounds"] + (
+            0 if eng.prefill_chunk else eng.stats["prefill_calls"])
 
 
 def test_flight_recorder_scopes_serve(tmp_path):

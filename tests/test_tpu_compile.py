@@ -44,10 +44,16 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernels: tuple[str, ...]):
+    """Compile ``fn`` for the described chip; each of ``kernels`` must be
+    named in the program (the Pallas call's ``name`` and its
+    ``kernel_metadata``, which the profiler's trace carries)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in kernels:
+        assert f'"kernel":"{name}"' in text, f"{name} is not named"
     return compiled
 
 
@@ -62,8 +68,10 @@ def test_flash_attention_compiles(one_chip, heads):
 
     shapes = [((B, S, H, Dh), bf), ((B, S, K, Dh), bf), ((B, S, K, Dh), bf)]
     _compile(lambda q, k, v: fa.flash_attention(q, k, v, interpret=False),
-             one_chip, *shapes)
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *shapes)
+             one_chip, *shapes, kernels=("flash_attention_fwd",))
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *shapes,
+             kernels=("flash_attention_fwd", "flash_attention_bwd_dq",
+                      "flash_attention_bwd_dkv"))
 
 
 @pytest.mark.parametrize("heads", [SMOLLM, NEMO], ids=["smollm", "nemo"])
@@ -73,7 +81,7 @@ def test_decode_attention_compiles(one_chip, heads):
     _compile(lambda q, k, v, pos, live: da.decode_attention(
         q, k, v, pos, live=live, interpret=False), one_chip,
         ((B, 1, H, Dh), bf), ((B, Smax, K, Dh), bf), ((B, Smax, K, Dh), bf),
-        ((B,), jnp.int32), ((B,), jnp.bool_))
+        ((B,), jnp.int32), ((B,), jnp.bool_), kernels=("decode_attention",))
 
 
 def test_decode_attention_paged_compiles(one_chip):
@@ -84,7 +92,7 @@ def test_decode_attention_paged_compiles(one_chip):
         q, k, v, pos, tbl, live=live, interpret=False), one_chip,
         ((B, 1, H, Dh), bf), ((n_blocks, bs, K, Dh), bf),
         ((n_blocks, bs, K, Dh), bf), ((B,), jnp.int32), ((B, nb), jnp.int32),
-        ((B,), jnp.bool_))
+        ((B,), jnp.bool_), kernels=("decode_attention_paged",))
 
 
 # decode batch (16 slots), one prefill chunk row (256 tokens) and a chunk
@@ -97,14 +105,17 @@ def test_multi_lora_compiles(one_chip, T, d_out):
     bf, f32 = jnp.bfloat16, jnp.float32
     x, idx = ((T, d_in), bf), ((T,), jnp.int32)
     _compile(lambda x, A, B, i: ml.multi_lora(x, A, B, i, interpret=False),
-             one_chip, x, ((U, d_in, r), f32), ((U, r, d_out), f32), idx)
+             one_chip, x, ((U, d_in, r), f32), ((U, r, d_out), f32), idx,
+             kernels=("multi_lora",))
     _compile(lambda x, A, B, i: ml.multi_lora_grouped(x, A, B, i,
                                                       interpret=False),
-             one_chip, x, ((64, d_in, r), f32), ((64, r, d_out), f32), idx)
+             one_chip, x, ((64, d_in, r), f32), ((64, r, d_out), f32), idx,
+             kernels=("multi_lora",))
     _compile(lambda x, aq, as_, bq, bs, i: ml.multi_lora_q8(
         x, aq, as_, bq, bs, i, interpret=False), one_chip, x,
         ((U, d_in, r), jnp.int8), ((U, d_in, 1), f32),
-        ((U, r, d_out), jnp.int8), ((U, r, 1), f32), idx)
+        ((U, r, d_out), jnp.int8), ((U, r, 1), f32), idx,
+        kernels=("multi_lora_q8",))
 
 
 def test_cola_fit_lowrank_compiles(one_chip):
@@ -113,7 +124,7 @@ def test_cola_fit_lowrank_compiles(one_chip):
     _compile(lambda x, g, A, B: ck.cola_fit_lowrank(x, g, A, B,
                                                     interpret=False),
              one_chip, ((T, d_in), f32), ((T, d_out), f32), ((d_in, r), f32),
-             ((r, d_out), f32))
+             ((r, d_out), f32), kernels=("cola_fit",))
 
 
 # ---------------------------------------------------------------------------
