@@ -349,16 +349,16 @@ def four_chip_phase(cfg, params, *, seed: int, batch: int = 8,
 
 def kernel_phase() -> dict:
     """Fail on any kernel-to-oracle fallback the run traced, except the known
-    gap: a prefill chunk (Sq > 1) has no decode-attention kernel yet."""
+    gap: a prefill chunk (Sq > 1) over the dense cache layout has no kernel
+    (the paged layout's has one)."""
     from repro.kernels import ops
     P = "kernels"
     known, unexpected = [], []
     for (op, shapes), n in sorted(ops.fallbacks().items()):
-        chunk = (op in ("decode_attention", "decode_attention_paged")
-                 and shapes[0][1][1] > 1)
+        chunk = op == "decode_attention" and shapes[0][1][1] > 1
         (known if chunk else unexpected).append(f"{op} {shapes} x{n}")
     for k in known:
-        log(P, f"known gap (chunked-prefill attention has no kernel): {k}")
+        log(P, f"known gap (dense-cache chunk attention has no kernel): {k}")
     for u in unexpected:
         log(P, f"unexpected fallback: {u}")
     check(not unexpected, P, f"{len(unexpected)} unexpected kernel fallbacks")

@@ -162,14 +162,24 @@ def sdpa_decode_paged(q: Array, k_pool: Array, v_pool: Array, positions: Array,
                       scale: float | None = None) -> Array:
     """Paged-KV incremental attention: the cache is a shared block pool
     (n_blocks, block, K, Dh) addressed through a per-slot ``block_table``
-    (B, max_blocks). The fused kernel scalar-prefetches the table and reads
-    pool blocks directly (no gather); the ref path gathers a dense per-slot
-    view. See ref.sdpa_decode_paged for semantics."""
+    (B, max_blocks). Both kernels scalar-prefetch the table and read pool
+    blocks directly (no gather): the query's length picks the single-query
+    decode kernel (Sq == 1) or the chunked-prefill kernel (Sq > 1); the ref
+    path gathers a dense per-slot view. See ref.sdpa_decode_paged for
+    semantics."""
     from repro.kernels import decode_attention as da
+    if q.shape[1] == 1:
+        op, ok, fn = ("decode_attention_paged",
+                      da.supported_paged(q, k_pool, v_pool, block_table),
+                      da.decode_attention_paged)
+    else:
+        op, ok, fn = ("prefill_attention_paged",
+                      da.supported_prefill_paged(q, k_pool, v_pool,
+                                                 block_table),
+                      da.prefill_attention_paged)
     return _route(
-        "decode_attention_paged",
-        da.supported_paged(q, k_pool, v_pool, block_table),
-        lambda q, kp, vp, pos, tbl, live, interpret: da.decode_attention_paged(
+        op, ok,
+        lambda q, kp, vp, pos, tbl, live, interpret: fn(
             q, kp, vp, pos, tbl, live=live, window=window, softcap=softcap,
             scale=scale, interpret=interpret),
         lambda: ref.sdpa_decode_paged(q, k_pool, v_pool, positions,

@@ -170,6 +170,137 @@ def test_ops_sdpa_decode_backend_switch():
                                rtol=1e-4, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# chunked prefill over the paged KV pool (Sq > 1)
+# ---------------------------------------------------------------------------
+
+SMOLLM_HEADS = dict(H=9, K=3, D=64)
+NEMO_HEADS = dict(H=32, K=8, D=128)
+
+
+def _paged_chunk_case(seed, Sq, H, K, D, positions, dtype=jnp.float32,
+                      bs=16, n_blocks=64, max_blocks=24):
+    """One chunk round over a shared pool: each row owns distinct, shuffled
+    pool blocks for its positions 0 .. pos + Sq - 1; the rest of its table
+    points at block 0 (foreign contents), as the pager leaves unallocated
+    entries."""
+    rng = np.random.default_rng(seed)
+    B = len(positions)
+    q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), dtype)
+    k_pool = jnp.asarray(rng.normal(size=(n_blocks, bs, K, D)), dtype)
+    v_pool = jnp.asarray(rng.normal(size=(n_blocks, bs, K, D)), dtype)
+    table = np.zeros((B, max_blocks), np.int32)
+    free = iter(rng.permutation(np.arange(1, n_blocks)))
+    for b, p in enumerate(positions):
+        for j in range(min(max_blocks, -(-(p + Sq) // bs))):
+            table[b, j] = next(free)
+    return (q, k_pool, v_pool, jnp.asarray(positions, jnp.int32),
+            jnp.asarray(table))
+
+
+@pytest.fixture
+def prefill_spy(monkeypatch):
+    """Calls of each paged kernel made through ``ops`` in interpret mode
+    (a refused shape would fall back to the oracle silently there)."""
+    calls = {"prefill": 0, "decode": 0}
+    for name, fn in (("prefill", da.prefill_attention_paged),
+                     ("decode", da.decode_attention_paged)):
+        def spy(*a, _fn=fn, _name=name, **kw):
+            calls[_name] += 1
+            return _fn(*a, **kw)
+        monkeypatch.setattr(da, f"{name}_attention_paged", spy)
+    ops.set_backend("pallas_interpret")
+    yield calls
+    ops.set_backend(None)
+
+
+@pytest.mark.parametrize("heads", [SMOLLM_HEADS, NEMO_HEADS],
+                         ids=["smollm", "nemo"])
+@pytest.mark.parametrize("Sq", [16, 5], ids=["chunk", "tail"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_prefill_attention_paged_matches_oracle(prefill_spy, heads, Sq,
+                                                dtype):
+    """Rows at position 0, mid-page and across many pages (several KV tiles)
+    of a shuffled, non-contiguous block table, against the gather oracle."""
+    q, kp, vp, pos, table = _paged_chunk_case(
+        16, Sq, heads["H"], heads["K"], heads["D"], [0, 21, 300, 7],
+        dtype=dtype)
+    assert da.supported_prefill_paged(q, kp, vp, table)
+    o = ops.sdpa_decode_paged(q, kp, vp, pos, table)
+    assert prefill_spy == {"prefill": 1, "decode": 0}
+    o_ref = ref.sdpa_decode_paged(q, kp, vp, pos, table)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("window,softcap", [(None, 30.0), (40, None)])
+def test_prefill_attention_paged_dead_rows_softcap_window(prefill_spy,
+                                                          window, softcap):
+    """Dead rows come back as exact zeros; softcap (Gemma-2's global
+    layers) and a local window mask as the oracle does."""
+    q, kp, vp, pos, table = _paged_chunk_case(
+        17, 16, NEMO_HEADS["H"], NEMO_HEADS["K"], NEMO_HEADS["D"],
+        [130, 0, 57, 250])
+    live = jnp.asarray([True, False, True, False])
+    o = ops.sdpa_decode_paged(q, kp, vp, pos, table, live=live,
+                              window=window, softcap=softcap)
+    assert prefill_spy["prefill"] == 1
+    o_ref = ref.sdpa_decode_paged(q, kp, vp, pos, table, live=live,
+                                  window=window, softcap=softcap)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               **_tol(jnp.float32))
+    assert np.all(np.asarray(o)[~np.asarray(live)] == 0.0)
+
+
+def test_paged_kernel_chosen_by_query_length(prefill_spy):
+    """Sq == 1 still runs the single-query decode kernel; Sq > 1 runs the
+    chunked-prefill kernel."""
+    q, kp, vp, pos, table = _paged_chunk_case(18, 1, 4, 2, 64, [3, 40])
+    ops.sdpa_decode_paged(q, kp, vp, pos, table)
+    assert prefill_spy == {"prefill": 0, "decode": 1}
+    q2 = jnp.concatenate([q, q], axis=1)
+    ops.sdpa_decode_paged(q2, kp, vp, pos, table)
+    assert prefill_spy == {"prefill": 1, "decode": 1}
+
+
+def test_paged_kernels_gate_the_cells_shapes():
+    """Both serving cells' chunk rounds and decode ticks are accepted by
+    their kernel's gate, and by no other."""
+    S, bf, i32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.int32
+    for B, C, H, K, D, n_blocks, max_blocks in (
+            (16, 256, 32, 8, 128, 2048, 289),      # nemo10.serve.tenants
+            (64, 128, 9, 3, 64, 5184, 81)):        # smollm.serve.chat
+        pool = S((n_blocks, 16, K, D), bf)
+        table = S((B, max_blocks), i32)
+        chunk, tick = S((B, C, H, D), bf), S((B, 1, H, D), bf)
+        assert da.supported_prefill_paged(chunk, pool, pool, table)
+        assert not da.supported_paged(chunk, pool, pool, table)
+        assert da.supported_paged(tick, pool, pool, table)
+        assert not da.supported_prefill_paged(tick, pool, pool, table)
+
+
+def test_platform_routing_counts_no_paged_fallback(monkeypatch):
+    """On a TPU process a paged chunk and a paged decode tick both have a
+    kernel: no fallback is counted (the CPU lowering here is the oracle)."""
+    q, kp, vp, pos, table = _paged_chunk_case(19, 16, 4, 2, 64, [0, 35])
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    ops.reset_fallbacks()
+    try:
+        chunk = jax.jit(ops.sdpa_decode_paged)(q, kp, vp, pos, table)
+        tick = jax.jit(ops.sdpa_decode_paged)(q[:, :1], kp, vp, pos, table)
+        assert ops.fallbacks() == {}
+    finally:
+        ops.reset_fallbacks()
+    np.testing.assert_allclose(
+        np.asarray(chunk), np.asarray(ref.sdpa_decode_paged(q, kp, vp, pos,
+                                                            table)),
+        rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(tick), np.asarray(ref.sdpa_decode_paged(q[:, :1], kp, vp,
+                                                           pos, table)),
+        rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("T,U,din,dout,r", [(128, 2, 64, 64, 4),
                                             (256, 8, 128, 96, 8),
                                             (64, 3, 192, 128, 16)])

@@ -2,12 +2,14 @@
 
 Acceptance invariants:
 - chunked prefill (``prefill_chunk=C``) emits exactly the tokens of unchunked
-  prefill on every layer plan — bit-identical logits for attention plans,
-  token-exact (argmax) with tight logit tolerance for recurrent/moe plans,
-  including prompts with an ``S % C != 0`` tail chunk;
+  prefill on every layer plan — logits equal up to float32 reassociation
+  for attention plans, token-exact (argmax) with tight logit tolerance for
+  recurrent/moe plans, including prompts with an ``S % C != 0`` tail chunk;
 - the paged KV layout (``kv_layout="paged"``) is bit-identical to the dense
-  chunked run on *every* plan (pool + block table is a relayout, not a
-  renumeration), and the fused paged Pallas kernel matches its gather oracle;
+  chunked run on every plan whose layers read a cache of the same length
+  (pool + block table is a relayout, not a renumeration), and equal up to
+  float32 reassociation where a local layer reads its ring; the fused paged
+  Pallas kernel matches its gather oracle;
 - HBM accounting: ``kv_cache_bytes()`` under the paged layout scales with
   blocks actually in use, not the horizon, and a paged engine admits prompts
   longer than the dense engine's old ``max_len`` ceiling with a small pool;
@@ -158,8 +160,8 @@ def test_paged_kernel_matches_oracle(window, softcap):
 
 def test_ring_oracle_bit_identical_to_dense_window():
     """The rolling ring cache (pairs local layers under the paged layout) with
-    position p at ring index p % W_ring reads back bit-identically to the
-    dense windowed oracle — including positions that have wrapped the ring."""
+    position p at ring index p % W_ring reads back the dense windowed
+    oracle's values — including positions that have wrapped the ring."""
     rng = np.random.default_rng(0)
     B, K, Dh, Smax = 4, 2, 64, 32
     W, C = 5, 3
@@ -178,7 +180,12 @@ def test_ring_oracle_bit_identical_to_dense_window():
     o_dense = ref.sdpa_decode(q, kd, vd, jnp.asarray(pos), live=live, window=W)
     o_ring = ref.sdpa_decode_ring(q, kr, vr, jnp.asarray(pos), live=live,
                                   window=W)
-    np.testing.assert_array_equal(np.asarray(o_ring), np.asarray(o_dense))
+    # the same keys, values, masks, maxima and softmax denominators either
+    # way; XLA:CPU blocks the P.V product by the key axis's length (W_ring
+    # = 7 here, Smax = 32 dense), so its float32 sums reassociate: 245 of
+    # the 1024 outputs differ, by at most 1.8e-7 absolute
+    np.testing.assert_allclose(np.asarray(o_ring), np.asarray(o_dense),
+                               rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -232,11 +239,18 @@ def test_chunked_matches_prefill_and_paged_matches_dense(name):
                       C=C, slot=s, slots=slots, recurrent=recurrent)
     assert int(np.argmax(lg_d)) == int(np.argmax(lg_full))
     if case["exact"] and not recurrent:
-        np.testing.assert_array_equal(lg_d, lg_full)
+        # the same attention, but XLA:CPU blocks the Q.K and P.V products by
+        # their shapes (a 4-query chunk against the Smax = 32 cache, or the
+        # whole prompt against itself), so float32 sums reassociate: at most
+        # 4.9e-7 (gemma2-9b) and 3.7e-7 (smollm-135m) apart
+        np.testing.assert_allclose(lg_d, lg_full, rtol=0, atol=4e-6)
     else:
         np.testing.assert_allclose(lg_d, lg_full, atol=1e-3)
 
-    # paged relayout: bit-identical to the dense chunked run on every plan
+    # paged relayout: bit-identical to the dense chunked run wherever both
+    # read a cache of the same length; a local layer of the pairs plan reads
+    # its W_ring ring, which sums as the ring test above does (gemma2-9b: at
+    # most 6.6e-7 apart)
     plan = M.layer_plan(cfg)
     ring_len = cfg.local_window + C - 1 if plan[0] == "pairs" else None
     pager = BlockPager(n_blocks=16, block_size=8, slots=slots, max_len=max_len)
@@ -245,7 +259,11 @@ def test_chunked_matches_prefill_and_paged_matches_dense(name):
                            kv_blocks=16, kv_block=8, ring_len=ring_len)
     lg_p = _chunk_run(cfg, params, prompt, cache_p, C=C, slot=s, slots=slots,
                       recurrent=recurrent, pager=pager)
-    np.testing.assert_array_equal(lg_p, lg_d)
+    if ring_len is None:
+        np.testing.assert_array_equal(lg_p, lg_d)
+    else:
+        assert int(np.argmax(lg_p)) == int(np.argmax(lg_d))
+        np.testing.assert_allclose(lg_p, lg_d, rtol=0, atol=5e-6)
 
 
 # ---------------------------------------------------------------------------

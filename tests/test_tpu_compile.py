@@ -9,6 +9,7 @@ described inside a module fixture (never at import), and every test of this
 kind lives in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
 from repro.kernels import multi_lora as ml
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOLLM = dict(H=9, K=3, Dh=64)
 NEMO = dict(H=32, K=8, Dh=128)
 
@@ -93,6 +95,65 @@ def test_decode_attention_paged_compiles(one_chip):
         ((B, 1, H, Dh), bf), ((n_blocks, bs, K, Dh), bf),
         ((n_blocks, bs, K, Dh), bf), ((B,), jnp.int32), ((B, nb), jnp.int32),
         ((B,), jnp.bool_), kernels=("decode_attention_paged",))
+
+
+def _hlo_with_operand_shapes(compiled) -> str:
+    """The optimized HLO as the profiler's trace names an op: operands
+    printed with their shapes (without the printer's ``/*index=N*/``
+    comments, which the trace does not carry)."""
+    from jax._src.lib import xla_client as xc
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_percent = True
+    text = compiled._executable.xla_extension_executable().hlo_modules()[
+        0].to_string(opts)
+    return re.sub(r"/\*index=\d+\*/", "", text)
+
+
+def _decode_roofline_pattern() -> str:
+    """``KERNEL`` of the benchmark's decode-roofline reader, read from its
+    file (the benchmark is not a package of the program)."""
+    import ast
+    path = os.path.join(ROOT, "bench", "metrics",
+                        "decode_attention_paged_roofline.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    return next(ast.literal_eval(node.value) for node in tree.body
+                if isinstance(node, ast.Assign)
+                and [t.id for t in node.targets] == ["KERNEL"])
+
+
+# both serving cells' chunk rounds: (slots, chunk, H, K, Dh, pool blocks,
+# table width), pages of 16 positions
+PREFILL_CELLS = {"nemo10": (16, 256, 32, 8, 128, 2048, 289),
+                 "smollm": (64, 128, 9, 3, 64, 5184, 81)}
+
+
+@pytest.mark.parametrize("cell", sorted(PREFILL_CELLS))
+def test_prefill_attention_paged_compiles(one_chip, cell):
+    """The chunked-prefill kernel at each serving cell's chunk shapes, in
+    one program with the paged decode kernel: the decode roofline's operand
+    pattern finds the decode kernel's custom call and not the new one."""
+    B, C, H, K, Dh, n_blocks, nb = PREFILL_CELLS[cell]
+    bf = jnp.bfloat16
+
+    def both(qc, qd, k, v, pos, tbl, live):
+        return (da.prefill_attention_paged(qc, k, v, pos, tbl, live=live),
+                da.decode_attention_paged(qd, k, v, pos, tbl, live=live))
+
+    compiled = _compile(
+        both, one_chip, ((B, C, H, Dh), bf), ((B, 1, H, Dh), bf),
+        ((n_blocks, 16, K, Dh), bf), ((n_blocks, 16, K, Dh), bf),
+        ((B,), jnp.int32), ((B, nb), jnp.int32), ((B,), jnp.bool_),
+        kernels=("prefill_attention_paged", "decode_attention_paged"))
+    decode_pat = _decode_roofline_pattern()
+    calls = {}     # each custom call, by the instruction name it is given
+    for line in _hlo_with_operand_shapes(compiled).splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*%(\w+)\.\d+ = ", line).group(1)
+            calls[name] = bool(re.search(decode_pat, line))
+    assert calls == {"prefill_attention_paged": False,
+                     "decode_attention_paged": True}
 
 
 # decode batch (16 slots), one prefill chunk row (256 tokens) and a chunk
