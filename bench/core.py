@@ -5,8 +5,9 @@ A cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a
 configuration and a traffic mix; each is a file found by its name:
 
 - ``bench/configs/<config>.json``: the published sizes, what was cut, and the
-  family whose reference (``bench/reference/<family>.py``) and counts
-  (``bench/roofline/<family>.py``) apply;
+  family whose builder (``bench/families/<family>.py``), reference
+  (``bench/reference/<family>.py``) and counts (``bench/roofline/<family>.py``)
+  apply (``program.py`` states what each provides);
 - ``bench/traffic/<traffic>.json``: the mix, read by ``traffic.py``, whose
   ``kind`` picks the driver (``bench/<kind>.py``);
 - ``bench/metrics/<metric>.py``: one reader per metric, ``read(run)``, which

@@ -2,7 +2,8 @@
 (``_kernel_paged``) reaches over the traced decode ticks, in %: the least
 time the chip could take for the live rows' work, max(operations / peak
 FLOP/s, bytes / peak bandwidth), over the kernel's device time. One call per
-layer per decode tick; the bandwidth bound applies at these shapes.
+layer per decode tick, each layer's work with its own window of keys; the
+bandwidth bound applies at these shapes.
 
 The trace names a Pallas kernel only by its HLO text, so the kernel is found
 by its operands: three scalar-prefetch ints (positions, live, and the 2-D
@@ -20,11 +21,8 @@ def read(run):
     hit = run.get("trace") and tr.op_time(run["trace"], KERNEL)
     if not hit:
         return None
-    m, pk = readers.dims(run), run["peak"]
     cost = core.module("roofline", "decode_attention_paged", run["root"]).cost
-    least = 0.0
-    for tick in readers.traced_ticks(run):
-        if tick["decode"]:
-            f, b = cost(tick["decode"], m["H"], m["K"], m["Dh"])
-            least += m["L"] * max(f / pk["bf16_flops"], b / pk["hbm_bw"])
+    layers = readers.windows(run)
+    least = sum(readers.least_s(run, cost, tick["decode"], layers)
+                for tick in readers.traced_ticks(run) if tick["decode"])
     return readers.share(least, hit[0])

@@ -9,6 +9,6 @@ def read(run):
     if not t:
         return None
     cnt, rank = readers.counts(run), run["traffic"]["users"]["rank"]
-    flops = sum(cnt.forward_flops(run["config"], 1, p + 1, rank, 1)
+    flops = sum(cnt.forward_flops(run["config"], p, 1, rank, 1)
                 for tick in readers.traced_ticks(run) for p in tick["decode"])
     return readers.share(flops / run["peak"]["bf16_flops"], t[0])

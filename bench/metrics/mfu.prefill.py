@@ -1,7 +1,8 @@
 """Useful operations of the traced chunk rounds over their program's device
 time times the chip's peak, in %. Useful: each real prompt token through
-every block, causal attention over its real context, and the output head at
-a prompt's last position only; padding rows and padded positions are not."""
+every block, causal attention over the real context each layer reads, and
+the output head at a prompt's last position only; padding rows and padded
+positions are not."""
 from bench import readers
 from bench import trace as tr
 
@@ -11,8 +12,7 @@ def read(run):
     if not t:
         return None
     cnt, rank = readers.counts(run), run["traffic"]["users"]["rank"]
-    flops = sum(cnt.forward_flops(run["config"], c, cnt.attn_pairs(p, c),
-                                  rank, int(last))
+    flops = sum(cnt.forward_flops(run["config"], p, c, rank, int(last))
                 for tick in readers.traced_ticks(run)
                 for p, c, last in tick["chunk"])
     return readers.share(flops / run["peak"]["bf16_flops"], t[0])

@@ -2,9 +2,10 @@
 (``prefill_attention_paged``) reaches over the traced chunk rounds, in %:
 the least time the chip could take for the prefilling rows' work, max
 (operations / peak FLOP/s, bytes / peak bandwidth), over the kernel's device
-time. One call per layer per chunk round. The kernel is found by the name
-it carries in the trace; a program without it (or one whose chunks fell
-back to the oracle) gives nothing to read."""
+time. One call per layer per chunk round, each layer's work with its own
+window of keys. The kernel is found by the name it carries in the trace; a
+program without it (or one whose chunks fell back to the oracle) gives
+nothing to read."""
 from bench import core, phases, readers
 from bench import trace as tr
 
@@ -14,12 +15,9 @@ def read(run):
         run["trace"], phases.kernel("prefill_attention_paged"))
     if not hit:
         return None
-    m, pk = readers.dims(run), run["peak"]
     cost = core.module("roofline", "prefill_attention_paged", run["root"]).cost
-    least = 0.0
-    for tick in readers.traced_ticks(run):
-        if tick["chunk"]:
-            f, b = cost([(p, c) for p, c, _ in tick["chunk"]],
-                        m["H"], m["K"], m["Dh"])
-            least += m["L"] * max(f / pk["bf16_flops"], b / pk["hbm_bw"])
+    layers = readers.windows(run)
+    least = sum(readers.least_s(run, cost,
+                                [(p, c) for p, c, _ in tick["chunk"]], layers)
+                for tick in readers.traced_ticks(run) if tick["chunk"])
     return readers.share(least, hit[0])

@@ -1,77 +1,57 @@
-"""The system under test, as the benchmark builds it: the program's
-``ModelConfig`` from a configuration file, and the seeded weights and adapters
-(``seeded.py``) placed in the program's own parameter layout, on the device,
-each in one jitted call."""
+"""The system under test, as the benchmark builds it: found by the
+configuration file's ``family``. A family is four files, each found by that
+name, so that a model of another architecture joins the benchmark as new
+files only:
+
+- ``bench/families/<family>.py`` builds the program's side, each value made
+  on the device from the seed in one jitted call:
+
+  - ``model_config(c, **program)``: the program's
+    ``repro.configs.base.ModelConfig`` of configuration ``c``;
+    ``program`` carries a mix's own program settings (``loss_chunk``...);
+    a configuration the family cannot build raises;
+  - ``params(c, seed)``: the program's parameter tree, in the
+    configuration's dtype;
+  - ``user_adapters(c, seed, users, rank)``: one adapter pytree per user,
+    as ``ServeEngine(user_adapters=)`` takes them.
+
+  Weights come from ``bench/seeded.py``: a family's leaves of its own take
+  ids from ``seeded.FAMILY_LEAVES`` up, declared with ``seeded.leaves``.
+- ``bench/reference/<family>.py``, the plain reference, which imports
+  nothing of the program and rebuilds every weight from the seed:
+
+  - ``hidden(c, seed, seqs, users, rank, *, weights, rows, multiple)``:
+    last hidden states of token sequences, user ``users[i]``'s adapters on
+    sequence i, as one (n, S, d) array; ``weights="fp8"`` is the control;
+  - ``head_stats(c, seed, xs, seqs, gather, *, weights)``: at each
+    position the best logit, its token, and the logits of the tokens
+    ``gather[i][p]``;
+  - ``logits(c, seed, seqs, reads, users, rank, *, weights)``: full logits
+    at some positions (small sizes, for tests);
+  - ``lora_init(c, key, rank)``: the adapters a training session starts
+    from, rebuilt from the session's key;
+  - ``train_steps(c, seed, adapter_key, rank, batches, opt, *, weights)``:
+    each step's loss, the first gradient and the adapters' change.
+- ``bench/roofline/<family>.py``, the counts of the work the algorithm
+  needs (a multiply-add is 2 operations):
+
+  - ``layer_windows(c)``: for each layer, how many keys a query attends to
+    (itself included), or None where it attends to its whole context;
+    the kernels' roofline readers add up each layer's least time with it;
+  - ``forward_flops(c, pos, n, rank, heads)``: n causal queries at
+    positions pos .. pos + n - 1 through every layer, each layer's
+    attention over the keys it reads, adapters of ``rank``, and ``heads``
+    rows of the output head (``mfu.prefill``, ``mfu.decode``);
+  - ``train_flops_per_token(c, seq, rank)``: ColA's required work per
+    trained token on sequences of ``seq`` (``mfu.train``).
+- ``bench/configs/<name>.json``: the configuration, naming its family.
+"""
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-
-from bench import seeded
-
-DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+from bench import core
 
 
-def model_config(c: dict, **program):
-    """``repro.configs.base.ModelConfig`` of a dense GQA configuration file.
-    ``program`` carries the program's own settings (``loss_chunk``...)."""
-    from repro.configs.base import ModelConfig
-    m = seeded.dims(c)
-    if c.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"{c['name']}: only silu gated MLPs are supported")
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=m["L"], d_model=m["d"],
-        n_heads=m["H"], n_kv_heads=m["K"], d_head=m["Dh"], d_ff=m["F"],
-        vocab_size=m["V"], rope_theta=float(c["rope_theta"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        norm_eps=float(c["rms_norm_eps"]), param_dtype=c["dtype"],
-        compute_dtype=c["dtype"], **program)
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _params(cj: tuple, key: jax.Array) -> dict:
-    c = dict(cj)
-    dt = DTYPES[c["dtype"]]
-    L = seeded.dims(c)["L"]
-    w = jax.vmap(lambda l: seeded.layer_weights(c, key, l))(jnp.arange(L))
-    w = jax.tree.map(lambda a: a.astype(dt), w)
-    p = {"embed": {"emb": seeded.embedding(c, key).astype(dt)},
-         "layers": {"ln1": {"scale": w["ln1"]}, "ln2": {"scale": w["ln2"]},
-                    "attn": {n: {"w": w[n]} for n in ("q", "k", "v", "o")},
-                    "mlp": {n: {"w": w[n]} for n in ("gate", "up", "down")}},
-         "final_norm": {"scale": seeded.final_norm(c, key).astype(dt)}}
-    if not c["tie_word_embeddings"]:
-        p["lm_head"] = {"w": seeded.head(c, key).astype(dt)}
-    return p
-
-
-def _frozen(c: dict) -> tuple:
-    return tuple(sorted((k, v) for k, v in c.items()
-                        if isinstance(v, (int, float, str, bool))))
-
-
-def params(c: dict, seed: int) -> dict:
-    """The program's parameter tree, in the configuration's dtype."""
-    return _params(_frozen(c), seeded.root_key(seed))
-
-
-@functools.partial(jax.jit, static_argnums=(0, 2, 3))
-def _bank(cj: tuple, key: jax.Array, users: int, rank: int) -> dict:
-    c = dict(cj)
-    L = seeded.dims(c)["L"]
-    per = jax.vmap(lambda u: jax.vmap(
-        lambda l: seeded.adapter_layer(c, key, u, l, rank))(jnp.arange(L)))(
-        jnp.arange(users))                       # leaves (U, L, ...)
-    return {f"layers.attn.{tap}": {n: jnp.moveaxis(a, 0, 1)
-                                   for n, a in leaves.items()}
-            for tap, leaves in per.items()}      # leaves (L, U, ...)
-
-
-def user_adapters(c: dict, seed: int, users: int, rank: int) -> list[dict]:
-    """One adapter pytree per user, as ``ServeEngine(user_adapters=)`` takes
-    them: {tap: {"A": (L, d, r), "B": (L, r, d_out)}} in float32."""
-    bank = _bank(_frozen(c), seeded.root_key(seed), users, rank)
-    return [{tap: {n: a[:, u] for n, a in leaves.items()}
-             for tap, leaves in bank.items()} for u in range(users)]
+def family(c: dict, root: str = core.ROOT):
+    """The family file (``bench/families/<family>.py``) of configuration
+    ``c`` in the checkout ``root``."""
+    return core.module("families", c["family"], root)

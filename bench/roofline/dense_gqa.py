@@ -1,10 +1,11 @@
 """Operation counts of a dense GQA decoder (``bench/reference/dense_gqa.py``)
 from its configuration file. Counts are of the work the algorithm needs: a
 multiply-add is 2 operations, padding rows and recomputation are not work.
+``bench/program.py`` states what a family's counts file provides.
 """
 from __future__ import annotations
 
-from bench import seeded
+from bench import readers, seeded
 
 
 def layer_params(c: dict) -> int:
@@ -20,19 +21,41 @@ def adapter_params(c: dict, rank: int) -> int:
     return rank * (m["d"] + m["H"] * m["Dh"]) + rank * (m["d"] + m["K"] * m["Dh"])
 
 
-def attn_pairs(pos: int, n: int) -> int:
+def layer_windows(c: dict) -> list[int | None]:
+    """Every layer attends to the whole context: the family's builder and
+    reference set no window."""
+    return [None] * seeded.dims(c)["L"]
+
+
+def attn_pairs(pos: int, n: int, window: int | None = None) -> int:
     """(query, key) pairs of n causal queries at positions pos .. pos+n-1."""
-    return n * pos + n * (n + 1) // 2
+    return readers.causal_pairs(pos, n, window)
 
 
-def forward_flops(c: dict, n: int, pairs: int, rank: int, heads: int) -> float:
-    """n tokens through every block (with adapters of ``rank``), ``pairs``
-    causal (query, key) pairs per layer, and ``heads`` rows of the output
-    head."""
+def layer_pairs(c: dict, pos: int, n: int) -> list[int]:
+    """Each layer's (query, key) pairs for n queries at pos .. pos+n-1."""
+    return [attn_pairs(pos, n, w) for w in layer_windows(c)]
+
+
+def _blocks(c: dict, rank: int) -> int:
+    """One token through every block's projections and adapters."""
+    per_layer = layer_params(c) + adapter_params(c, rank)
+    return 2 * seeded.dims(c)["L"] * per_layer
+
+
+def _attn(c: dict, pos: int, n: int) -> int:
+    """QK and PV of n queries at pos .. pos+n-1, summed over the layers."""
     m = seeded.dims(c)
-    per_tok = 2 * (layer_params(c) + adapter_params(c, rank))
-    attn = 4 * m["H"] * m["Dh"] * pairs
-    return m["L"] * (n * per_tok + attn) + heads * 2 * m["d"] * m["V"]
+    return 4 * m["H"] * m["Dh"] * sum(layer_pairs(c, pos, n))
+
+
+def forward_flops(c: dict, pos: int, n: int, rank: int, heads: int) -> float:
+    """n causal queries at positions pos .. pos+n-1 through every block
+    (with adapters of ``rank``, each layer's attention over the keys it
+    reads), and ``heads`` rows of the output head."""
+    m = seeded.dims(c)
+    head = heads * 2 * m["d"] * m["V"]
+    return n * _blocks(c, rank) + _attn(c, pos, n) + head
 
 
 def train_flops_per_token(c: dict, seq: int, rank: int) -> float:
@@ -42,10 +65,9 @@ def train_flops_per_token(c: dict, seq: int, rank: int) -> float:
     attention backward is twice its forward. Remat recompute is not counted.
     """
     m = seeded.dims(c)
-    pairs = attn_pairs(0, seq) / seq                   # per token
-    fwd = forward_flops(c, 1, 0, rank, 1)               # without attention
-    attn_fwd = m["L"] * 4 * m["H"] * m["Dh"] * pairs
-    bwd_x = 2 * m["L"] * (layer_params(c) + adapter_params(c, rank)) \
-        + 2 * m["d"] * m["V"]
+    head = 2 * m["d"] * m["V"]
+    attn_fwd = _attn(c, 0, seq) / seq
     bwd_adapter = 2 * m["L"] * adapter_params(c, rank)
-    return fwd + attn_fwd + bwd_x + 2 * attn_fwd + bwd_adapter
+    fwd = _blocks(c, rank) + head + attn_fwd
+    bwd_x = _blocks(c, rank) + head
+    return fwd + bwd_x + 2 * attn_fwd + bwd_adapter

@@ -1,5 +1,6 @@
 """Values made from ``--seed``: the base weights of a dense GQA model, one
-layer at a time, and per-user low-rank adapters.
+layer at a time, per-user low-rank adapters, and the per-leaf normal that
+a family's own leaves are made from (``normal``, ``leaves``).
 
 Both the harness (which hands the weights to the program in its own layout)
 and the plain reference (which rebuilds them layer by layer in float32) call
@@ -13,10 +14,30 @@ import jax
 import jax.numpy as jnp
 
 # A fixed id per leaf: the key of a leaf never depends on which other leaves
-# a model has.
+# a model has. These are the dense block's; a family's own leaves take ids
+# from FAMILY_LEAVES up (``leaves``).
 LEAF = {"embed": 1, "lm_head": 2, "q": 3, "k": 4, "v": 5, "o": 6, "gate": 7,
         "up": 8, "down": 9, "ln1": 10, "ln2": 11, "final_norm": 12,
         "adapter": 100}
+FAMILY_LEAVES = 1000
+
+
+def leaves(own: dict[str, int]) -> dict[str, int]:
+    """``LEAF`` with a family's own leaves added, as its family file
+    declares them: {name: id}, each id a whole number from FAMILY_LEAVES
+    up, no two alike, and no name of ``LEAF``'s. Raises on a clash."""
+    taken = {i: n for n, i in LEAF.items()}
+    for name, i in own.items():
+        if name in LEAF:
+            raise ValueError(f"leaf {name!r} is already the dense block's "
+                             f"(id {LEAF[name]})")
+        if not isinstance(i, int) or i < FAMILY_LEAVES:
+            raise ValueError(f"leaf {name!r}: id {i!r} is not a whole number "
+                             f"from {FAMILY_LEAVES} up")
+        if i in taken:
+            raise ValueError(f"leaves {taken[i]!r} and {name!r} share id {i}")
+        taken[i] = name
+    return {**LEAF, **own}
 
 
 def root_key(seed: int) -> jax.Array:
@@ -37,9 +58,15 @@ def dims(c: dict) -> dict:
             "V": c["vocab_size"], "L": c["num_hidden_layers"]}
 
 
-def _normal(key, leaf: str, layer, shape, scale):
-    k = jax.random.fold_in(jax.random.fold_in(key, LEAF[leaf]), layer)
+def normal(key, leaf: int, layer, shape, scale):
+    """float32 N(0, scale^2) entries of the leaf with id ``leaf`` at
+    ``layer`` (which may be traced)."""
+    k = jax.random.fold_in(jax.random.fold_in(key, leaf), layer)
     return jax.random.normal(k, shape, jnp.float32) * scale
+
+
+def _normal(key, leaf: str, layer, shape, scale):
+    return normal(key, LEAF[leaf], layer, shape, scale)
 
 
 def layer_weights(c: dict, key: jax.Array, layer) -> dict:

@@ -42,10 +42,11 @@ def build(ctx) -> dict:
     import jax
     from repro.runtime.serve_loop import Request, ServeEngine
     c, mix = ctx.config, ctx.traffic
-    cfg = program.model_config(c)
-    params = program.params(c, ctx.seed)
+    fam = program.family(c, ctx.root)
+    cfg = fam.model_config(c)
+    params = fam.params(c, ctx.seed)
     users = mix["users"]
-    banks = program.user_adapters(c, ctx.seed, users["count"], users["rank"])
+    banks = fam.user_adapters(c, ctx.seed, users["count"], users["rank"])
     jax.block_until_ready((params, banks))
     e = mix["engine"]
     eng = ServeEngine(cfg, params, slots=e["slots"],

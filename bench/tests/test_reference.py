@@ -31,9 +31,10 @@ def test_reference_matches_program_forward():
     from repro.kernels import ops
     from repro.models import model as M
     c = tiny_cfg()
-    cfg = program.model_config(c)
-    params = program.params(c, 11)
-    ad = program.user_adapters(c, 11, 3, 4)[2]
+    fam = program.family(c)
+    cfg = fam.model_config(c)
+    params = fam.params(c, 11)
+    ad = fam.user_adapters(c, 11, 3, 4)[2]
     spec = taps_lib.make_spec(family="lowrank", taps=tuple(sorted(ad)))
     toks = np.random.default_rng(0).integers(0, 128, size=(1, 40))
     ops.set_backend("ref")
@@ -55,8 +56,9 @@ def test_training_reference_matches_program_gradient():
     from repro.kernels import ops
     from bench import seeded
     c = tiny_cfg()
-    cfg = program.model_config(c)
-    params = program.params(c, 5)
+    fam = program.family(c)
+    cfg = fam.model_config(c)
+    params = fam.params(c, 5)
     cc = ColaConfig(mode="fused_fit", family="lowrank", taps="qv", rank=4)
     ad = gl.init_adapters(cfg, cc, seeded.session_key(5))
     ref = core.module("reference", "dense_gqa")

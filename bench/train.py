@@ -57,8 +57,9 @@ def build(ctx) -> dict:
     from repro.core.session import ColaSession
     from repro.optim import optimizers as optim
     c, mix = ctx.config, ctx.traffic
-    cfg = program.model_config(c, **mix.get("program", {}))
-    params = program.params(c, ctx.seed)
+    fam = program.family(c, ctx.root)
+    cfg = fam.model_config(c, **mix.get("program", {}))
+    params = fam.params(c, ctx.seed)
     data = [jax.device_put(b) for b in traffic.train_batches(
         mix, ctx.seed, cfg.vocab_size)]
     o = optimizer(mix)
